@@ -17,7 +17,7 @@ from .common import emit, time_call
 
 def run(ns=(256, 512, 1024), nb=64):
     rows = []
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         for n in ns:
             ds = make_dataset(jax.random.PRNGKey(0), n, [1.0, 0.1, 0.5],
                               nu_static=0.5)

@@ -50,14 +50,9 @@ def run(n_rep=N_REP):
                 ds = make_dataset(jax.random.fold_in(jax.random.PRNGKey(42),
                                                      rep * 7 + 1),
                                   N, theta0, nu_static=0.5)
-                try:
-                    th, ne = fit_variant(ds, pol)
-                    ests.append(th)
-                    evals.append(ne)
-                except Exception:
-                    continue
-            if not ests:
-                continue
+                th, ne = fit_variant(ds, pol)
+                ests.append(th)
+                evals.append(ne)
             est = np.stack(ests)
             key = f"fig7/{level}/{vname}"
             results[key] = est

@@ -65,13 +65,10 @@ for name, pol in policies.items():
     res = fit_mle(None, coarse.theta, max_iters=50,
                   batched_loglik_fn=engine.loglik)
     n_evals = coarse.n_evals + res.n_evals
-    try:
-        score, _ = kfold_pmse(ds.locs, ds.z,
-                              jnp.array([res.theta[0], res.theta[1], 0.5]),
-                              pol if pol.mode != "dst"
-                              else PrecisionPolicy.full(jnp.float32),
-                              k=4, nb=args.nb, nu_static=0.5)
-    except Exception:
-        score = float("nan")
+    score, _ = kfold_pmse(ds.locs, ds.z,
+                          jnp.array([res.theta[0], res.theta[1], 0.5]),
+                          pol if pol.mode != "dst"
+                          else PrecisionPolicy.full(jnp.float32),
+                          k=4, nb=args.nb, nu_static=0.5)
     print(f"{name:28s} {res.theta[0]:8.3f} {res.theta[1]:10.4f} "
           f"{res.loglik:10.2f} {n_evals:6d} {score:8.4f}")

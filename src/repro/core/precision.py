@@ -81,7 +81,7 @@ class PrecisionPolicy:
     def paper_cpu(diag_thick: int) -> "PrecisionPolicy":
         """The paper's literal pair: DP=fp64 band, SP=fp32 off-band.
 
-        Requires x64 (use jax.experimental.enable_x64 or the config flag).
+        Requires x64 (use `with jax.enable_x64(True):` or the config flag).
         """
         return PrecisionPolicy(mode="mixed", hi=jnp.float64, lo=jnp.float32,
                                diag_thick=diag_thick,

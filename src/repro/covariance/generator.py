@@ -18,6 +18,7 @@ This substitution is recorded in DESIGN.md ("Changed assumptions").
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax
@@ -37,7 +38,7 @@ class Dataset(NamedTuple):
 def random_locations(key, n: int, *, lo: float = 0.0, hi: float = 1.0,
                      dtype=jnp.float32):
     """Irregular perturbed-grid locations in (lo, hi)^2 (ExaGeoStat style)."""
-    m = int(jnp.ceil(jnp.sqrt(n)))
+    m = math.ceil(math.sqrt(n))  # host math: n is static under jit
     xs, ys = jnp.meshgrid(jnp.arange(m), jnp.arange(m), indexing="ij")
     grid = jnp.stack([xs.reshape(-1), ys.reshape(-1)], axis=-1).astype(dtype)
     jitter = jax.random.uniform(key, (m * m, 2), minval=-0.4, maxval=0.4,

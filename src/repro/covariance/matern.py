@@ -57,7 +57,7 @@ def _chebev(coeffs: tuple, x):
     """Chebyshev series evaluation on [-1, 1] (Clenshaw).
 
     coeffs stay Python floats (weak-typed) so the series runs at x's dtype
-    -- including fp64 under jax.experimental.enable_x64.
+    -- including fp64 under `jax.enable_x64(True)`.
     """
     d = jnp.zeros_like(x)
     dd = jnp.zeros_like(x)

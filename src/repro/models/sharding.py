@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # logical axis -> preferred physical axes, in priority order.
 # "fsdp" rules shard parameters over the data axis (ZeRO-3 style); XLA
@@ -73,7 +73,15 @@ _ACTIVATION_MESH: list = [None]
 
 
 def set_activation_mesh(mesh):
-    """Install (or clear, with None) the mesh used by constrain()."""
+    """Install (or clear, with None) the mesh used by constrain().
+
+    with_sharding_constraint only accepts Auto mesh axes; the constructors
+    in launch/mesh.py build them (`jax.make_mesh` defaults to Explicit).
+    """
+    if mesh is not None and any(t != AxisType.Auto for t in mesh.axis_types):
+        raise ValueError(f"constrain() needs Auto mesh axes, got "
+                         f"{mesh.axis_types}; build the mesh with "
+                         f"repro.launch.mesh")
     _ACTIVATION_MESH[0] = mesh
 
 

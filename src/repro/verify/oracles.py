@@ -3,8 +3,10 @@
 Oracle convention: every oracle upcasts the SAME fp32 input matrix the
 mixed-precision path factors (rather than rebuilding the covariance in
 fp64), so the measured error isolates the factorization/solve chain from
-covariance-build rounding.  All oracle arithmetic runs under
-`jax.experimental.enable_x64()` and all metrics are computed in fp64.
+covariance-build rounding.  All oracle arithmetic runs in fp64 on the
+host CPU device (`jax.enable_x64(True)` pinned to `jax.devices("cpu")`, or
+NumPy/SciPy), never on the default device: an accelerator without fp64
+would silently answer in fp32.  All metrics are computed in fp64.
 
 Metrics (the quantities the tolerance registry bounds):
 
@@ -19,6 +21,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from scipy.linalg import solve_triangular
 
 # ---------------------------------------------------------------------------
 # fp64 reference answers
@@ -26,10 +29,14 @@ import numpy as np
 
 
 def exact_factor(cov) -> np.ndarray:
-    """fp64 dense lower Cholesky of (the upcast of) `cov`."""
-    with jax.experimental.enable_x64():
-        a = jnp.asarray(np.asarray(cov, np.float64))
+    """fp64 dense lower Cholesky of (the upcast of) `cov`, on the host CPU."""
+    cpu = jax.devices("cpu")[0]
+    with jax.enable_x64(True), jax.default_device(cpu):
+        a = jax.device_put(np.asarray(cov, np.float64), cpu)
         l = jnp.linalg.cholesky(a)
+        if l.dtype != jnp.float64 or l.devices() != {cpu}:
+            raise RuntimeError(f"fp64 oracle answered in {l.dtype} on "
+                               f"{l.devices()}, not fp64 on {cpu}")
         return np.asarray(l, np.float64)
 
 
@@ -39,7 +46,7 @@ def exact_loglik(cov, z) -> float:
     zz = np.asarray(z, np.float64)
     l = exact_factor(a)
     n = zz.shape[-1]
-    w = np.linalg.solve(l, zz)  # triangular; np.linalg.solve is exact enough
+    w = solve_triangular(l, zz, lower=True)
     return float(-0.5 * n * np.log(2.0 * np.pi)
                  - np.sum(np.log(np.diag(l))) - 0.5 * np.sum(w * w))
 

@@ -178,8 +178,8 @@ def test_float64_outside_x64_module_flagged():
 
 def test_float64_legal_when_module_enables_x64():
     src = """
-    from jax.experimental import enable_x64
-    x = jnp.float64
+    with jax.enable_x64(True):
+        x = jnp.float64
     """
     assert lint(src, RUNTIME) == []
 

@@ -106,8 +106,8 @@ def test_geostat_dag_cost_exact_counts():
 
 def test_collective_parser_on_real_hlo():
     """K-sharded matmul must produce one all-reduce of known size."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("model",))
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,))
     a = jax.ShapeDtypeStruct((64, 128), jnp.float32,
                              sharding=NamedSharding(mesh, P(None, "model")))
     b = jax.ShapeDtypeStruct((128, 32), jnp.float32,

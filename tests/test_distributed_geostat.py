@@ -75,3 +75,22 @@ def test_distributed_jits(ds):
     v1 = float(f(ds.theta0))
     v2 = float(f(ds.theta0 * 1.1))
     assert np.isfinite(v1) and np.isfinite(v2) and v1 != v2
+
+
+def test_distributed_build_matches_banded_for_near_neighbours():
+    """Points 1e-4 apart, far from the origin: the |a|^2+|b|^2-2ab^T
+    distance form cancels there (and a TPU's one-pass bf16 matmul made the
+    covariance indefinite); the build must match the one-chip build."""
+    pol = PrecisionPolicy.full()
+    key = jax.random.PRNGKey(3)
+    centres = 0.8 + 0.1 * jax.random.uniform(key, (N // 4, 1, 2))
+    offsets = 1e-4 * jnp.array([[0.0, 0.0], [1.0, 0.0], [0.0, 1.0],
+                                [1.0, 1.0]])
+    locs = (centres + offsets[None]).reshape(N, 2)
+    theta = jnp.array([1.0, 0.03])
+    off, band = build_covariance_distributed(locs, theta, nb=NB, policy=pol,
+                                             nu_static=0.5)
+    band_ref, _ = build_banded_covariance(locs, theta, nb=NB, policy=pol,
+                                          nu_static=0.5)
+    np.testing.assert_allclose(np.asarray(band), np.asarray(band_ref),
+                               rtol=2e-5, atol=2e-6)

@@ -21,7 +21,7 @@ XS = np.array([1e-4, 1e-2, 0.1, 0.5, 1.0, 1.9, 2.0, 2.1, 3.0, 5.0, 10.0, 30.0, 8
 
 @pytest.mark.parametrize("nu", NUS)
 def test_kv_matches_scipy_f64(nu):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         ours = np.asarray(kv(jnp.float64(nu), jnp.asarray(XS, jnp.float64)))
     ref = sp.kv(nu, XS)
     np.testing.assert_allclose(ours, ref, rtol=1e-10)

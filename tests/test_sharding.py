@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_smoke_mesh
 from repro.models.sharding import (DEFAULT_RULES, ax, batch_spec, constrain,
                                    resolve_spec, set_activation_mesh)
 
@@ -14,7 +15,7 @@ from repro.models.sharding import (DEFAULT_RULES, ax, batch_spec, constrain,
 def mesh2x2():
     if jax.device_count() < 1:
         pytest.skip("no devices")
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_smoke_mesh()
 
 
 def test_resolve_basic(mesh2x2):
@@ -65,6 +66,15 @@ def test_constrain_with_mesh(mesh2x2):
         np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
     finally:
         set_activation_mesh(None)
+
+
+def test_set_activation_mesh_rejects_explicit_axes():
+    from jax.sharding import AxisType
+    explicit = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Explicit,) * 2)
+    with pytest.raises(ValueError, match="Auto mesh axes"):
+        set_activation_mesh(explicit)
+    set_activation_mesh(None)
 
 
 def test_batch_spec_seq_sharded(mesh2x2):

@@ -52,7 +52,7 @@ def test_mixed_error_decreases_with_band(small_cov):
 
 
 def test_paper_cpu_pair_f64_f32(small_cov):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         cov64 = small_cov.astype(jnp.float64)
         pol = PrecisionPolicy.paper_cpu(diag_thick=2)
         l_mp = tile_cholesky(cov64, 32, pol)
