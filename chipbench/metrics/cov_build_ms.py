@@ -1,0 +1,8 @@
+"""Device time of the covariance build per evaluation (ms): ops under the
+named scope `geostat_loglik_step/cov_build` in `jit_cb_eval`."""
+
+from chipbench import scopes
+
+
+def read(rctx):
+    return scopes.scope_ms(rctx, "cb_eval", "geostat_loglik_step/cov_build")

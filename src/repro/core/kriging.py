@@ -61,19 +61,26 @@ def krige(locs_obs, z_obs, locs_new, theta, policy: PrecisionPolicy, *,
         # DST has no kriging variant; predict densely in hi precision (the
         # same convention the batch engine documents)
         policy, use_tiles = PrecisionPolicy.full(policy.hi), None
-    # Sigma_oo is built and factored by THE shared covariance/factor-path
-    # selection (make_factor_fn), so kriging can never pick a different
-    # precision path than the likelihood for the same policy
-    factor = make_factor_fn(locs_obs, policy, nb=nb, nu_static=nu_static,
-                            metric=metric, nugget=nugget, jitter=jitter,
-                            use_tiles=use_tiles)
-    l = factor(theta)
-    sigma_no = matern_covariance(locs_new, locs_obs, theta, nu_static=nu_static,
-                                 metric=metric).astype(policy.hi)
-    if not return_var:
-        return krige_from_factor(l, z_obs, sigma_no)
-    sigma_nn_diag = theta[..., 0:1] * jnp.ones(locs_new.shape[0], dtype=policy.hi)
-    return krige_from_factor(l, z_obs, sigma_no, sigma_nn_diag=sigma_nn_diag)
+    with jax.named_scope("krige"):
+        # Sigma_oo is built and factored by THE shared covariance/factor-path
+        # selection (make_factor_fn, scopes `cov_build` and `factor`), so
+        # kriging can never pick a different precision path than the
+        # likelihood for the same policy
+        factor = make_factor_fn(locs_obs, policy, nb=nb, nu_static=nu_static,
+                                metric=metric, nugget=nugget, jitter=jitter,
+                                use_tiles=use_tiles)
+        l = factor(theta)
+        with jax.named_scope("cov_build"):
+            sigma_no = matern_covariance(locs_new, locs_obs, theta,
+                                         nu_static=nu_static,
+                                         metric=metric).astype(policy.hi)
+        with jax.named_scope("solve"):
+            if not return_var:
+                return krige_from_factor(l, z_obs, sigma_no)
+            sigma_nn_diag = theta[..., 0:1] * jnp.ones(locs_new.shape[0],
+                                                       dtype=policy.hi)
+            return krige_from_factor(l, z_obs, sigma_no,
+                                     sigma_nn_diag=sigma_nn_diag)
 
 
 def pmse(mu, y_true):

@@ -102,11 +102,14 @@ def make_factor_fn(locs, policy: PrecisionPolicy, *, nb: int = 128,
     tiled = use_tiles if use_tiles is not None else policy.mode != "full"
 
     def factor(theta):
-        cov = build_covariance(locs, jnp.asarray(theta), nu_static=nu_static,
-                               metric=metric, nugget=nugget, jitter=jitter,
-                               dtype=policy.hi)
-        return tile_cholesky(cov, nb, policy) if tiled \
-            else reference_cholesky(cov, policy.hi)
+        with jax.named_scope("cov_build"):
+            cov = build_covariance(locs, jnp.asarray(theta),
+                                   nu_static=nu_static, metric=metric,
+                                   nugget=nugget, jitter=jitter,
+                                   dtype=policy.hi)
+        with jax.named_scope("factor"):
+            return tile_cholesky(cov, nb, policy) if tiled \
+                else reference_cholesky(cov, policy.hi)
 
     return factor
 

@@ -22,7 +22,6 @@ parallel likelihood evaluations in the ExaGeoStat follow-up work
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Callable
 
@@ -31,23 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
-
-
-def _timed_eval(fn: Callable | None, metric: str) -> Callable | None:
-    """Wrap an optimizer's (host-side, blocking) evaluation function so each
-    call lands one latency sample in the `metric` histogram.  Identity when
-    telemetry is off -- the optimizer hot loop pays nothing."""
-    if fn is None or not obs.enabled():
-        return fn
-
-    def timed(*args):
-        t0 = time.perf_counter()
-        out = fn(*args)
-        obs.observe(metric, time.perf_counter() - t0)
-        obs.inc(metric + ".calls")
-        return out
-
-    return timed
 
 
 @dataclass
@@ -78,13 +60,11 @@ def neldermead(fn: Callable, x0, *, xtol: float = 1e-3, ftol: float = 1e-6,
     factorization itself dominates, the speculative work can cost up to
     ~3x the FLOPs -- leave fn_batch unset there.  The accepted point is
     identical to the sequential algorithm's either way.
+
+    Each iteration is one `mle.iter` telemetry span; the initial simplex
+    is evaluated before the first.
     """
     x0 = np.asarray(x0, dtype=np.float64)
-    # per-evaluation latency histograms (mle.eval_seconds /
-    # mle.eval_batch_seconds): each fn call is a device round-trip, the
-    # paper's "time per iteration" unit
-    fn = _timed_eval(fn, "mle.eval_seconds")
-    fn_batch = _timed_eval(fn_batch, "mle.eval_batch_seconds")
     d = x0.size
     pts = [x0] + [x0 + scale * np.eye(d)[i] for i in range(d)]
     simplex = np.stack(pts)
@@ -99,48 +79,51 @@ def neldermead(fn: Callable, x0, *, xtol: float = 1e-3, ftol: float = 1e-6,
     converged = False
     it = 0
     for it in range(1, max_iters + 1):
-        order = np.argsort(fvals)
-        simplex, fvals = simplex[order], fvals[order]
-        history.append((simplex[0].copy(), fvals[0]))
-        if (np.max(np.abs(simplex[1:] - simplex[0])) < xtol
-                and np.max(np.abs(fvals[1:] - fvals[0])) < ftol):
-            converged = True
-            break
-        centroid = simplex[:-1].mean(axis=0)
-        xr = centroid + alpha * (centroid - simplex[-1])
-        xe = centroid + gamma * (xr - centroid)
-        xc = centroid + rho * (simplex[-1] - centroid)
-        if fn_batch is not None:
-            fr, fe, fc = np.asarray(
-                fn_batch(np.stack([xr, xe, xc])), dtype=np.float64)
-            n_evals += 3
-        else:
-            fr = float(fn(xr)); n_evals += 1
-            fe = fc = None
-        if fvals[0] <= fr < fvals[-2]:
-            simplex[-1], fvals[-1] = xr, fr
-        elif fr < fvals[0]:
-            if fe is None:
-                fe = float(fn(xe)); n_evals += 1
-            if fe < fr:
-                simplex[-1], fvals[-1] = xe, fe
+        with obs.span("mle.iter"):
+            order = np.argsort(fvals)
+            simplex, fvals = simplex[order], fvals[order]
+            history.append((simplex[0].copy(), fvals[0]))
+            if (np.max(np.abs(simplex[1:] - simplex[0])) < xtol
+                    and np.max(np.abs(fvals[1:] - fvals[0])) < ftol):
+                converged = True
+                break
+            centroid = simplex[:-1].mean(axis=0)
+            xr = centroid + alpha * (centroid - simplex[-1])
+            xe = centroid + gamma * (xr - centroid)
+            xc = centroid + rho * (simplex[-1] - centroid)
+            if fn_batch is not None:
+                fr, fe, fc = np.asarray(
+                    fn_batch(np.stack([xr, xe, xc])), dtype=np.float64)
+                n_evals += 3
             else:
+                fr = float(fn(xr)); n_evals += 1
+                fe = fc = None
+            if fvals[0] <= fr < fvals[-2]:
                 simplex[-1], fvals[-1] = xr, fr
-        else:
-            if fc is None:
-                fc = float(fn(xc)); n_evals += 1
-            if fc < fvals[-1]:
-                simplex[-1], fvals[-1] = xc, fc
-            else:  # shrink
-                if fn_batch is not None:
-                    simplex[1:] = simplex[0] + sigma * (simplex[1:] - simplex[0])
-                    fvals[1:] = np.asarray(fn_batch(simplex[1:]),
-                                           dtype=np.float64)
-                    n_evals += d
+            elif fr < fvals[0]:
+                if fe is None:
+                    fe = float(fn(xe)); n_evals += 1
+                if fe < fr:
+                    simplex[-1], fvals[-1] = xe, fe
                 else:
-                    for i in range(1, d + 1):
-                        simplex[i] = simplex[0] + sigma * (simplex[i] - simplex[0])
-                        fvals[i] = float(fn(simplex[i])); n_evals += 1
+                    simplex[-1], fvals[-1] = xr, fr
+            else:
+                if fc is None:
+                    fc = float(fn(xc)); n_evals += 1
+                if fc < fvals[-1]:
+                    simplex[-1], fvals[-1] = xc, fc
+                else:  # shrink
+                    if fn_batch is not None:
+                        simplex[1:] = simplex[0] + sigma * (simplex[1:]
+                                                            - simplex[0])
+                        fvals[1:] = np.asarray(fn_batch(simplex[1:]),
+                                               dtype=np.float64)
+                        n_evals += d
+                    else:
+                        for i in range(1, d + 1):
+                            simplex[i] = simplex[0] + sigma * (simplex[i]
+                                                               - simplex[0])
+                            fvals[i] = float(fn(simplex[i])); n_evals += 1
     order = np.argsort(fvals)
     return simplex[order][0], fvals[order][0], n_evals, it, converged, history
 
@@ -164,8 +147,9 @@ def fit_mle(loglik_fn: Callable, theta0, *, xtol: float = 1e-3,
     neg_batch = None
     if batched_loglik_fn is not None:
         def neg_batch(xs):
-            v = np.asarray(batched_loglik_fn(jnp.exp(jnp.asarray(xs))),
-                           dtype=np.float64)
+            with obs.span("mle.eval_batch"):
+                v = np.asarray(batched_loglik_fn(jnp.exp(jnp.asarray(xs))),
+                               dtype=np.float64)
             return np.where(np.isfinite(v), -v, 1e10)
 
     if loglik_fn is None:
@@ -178,8 +162,10 @@ def fit_mle(loglik_fn: Callable, theta0, *, xtol: float = 1e-3,
         ll = jax.jit(loglik_fn) if jit else loglik_fn
 
         def neg_ll_log(x):
-            v = ll(jnp.exp(jnp.asarray(x)))
-            v = float(v)
+            with obs.span("mle.theta"):
+                theta = jnp.exp(jnp.asarray(x))
+            with obs.span("mle.eval"):
+                v = float(ll(theta))
             return 1e10 if not np.isfinite(v) else -v
 
     with obs.span("mle.fit", driver="neldermead",
@@ -209,8 +195,6 @@ def fit_mle_grid(batched_loglik_fn: Callable, bounds, *, num: int = 12,
     bounds = np.asarray(bounds, dtype=np.float64)
     if bounds.ndim != 2 or bounds.shape[1] != 2 or np.any(bounds <= 0.0):
         raise ValueError("bounds must be (d, 2) with positive entries")
-    batched_loglik_fn = _timed_eval(batched_loglik_fn,
-                                    "mle.eval_batch_seconds")
     d = bounds.shape[0]
     lo0, hi0 = np.log(bounds[:, 0]), np.log(bounds[:, 1])
     lo, hi = lo0.copy(), hi0.copy()
@@ -222,8 +206,10 @@ def fit_mle_grid(batched_loglik_fn: Callable, bounds, *, num: int = 12,
             axes = [np.linspace(lo[i], hi[i], num) for i in range(d)]
             mesh = np.stack(np.meshgrid(*axes, indexing="ij"),
                             axis=-1).reshape(-1, d)
-            ll = np.asarray(batched_loglik_fn(jnp.exp(jnp.asarray(mesh))),
-                            dtype=np.float64)
+            with obs.span("mle.eval_batch"):
+                ll = np.asarray(
+                    batched_loglik_fn(jnp.exp(jnp.asarray(mesh))),
+                    dtype=np.float64)
             ll = np.where(np.isfinite(ll), ll, -np.inf)
             n_evals += mesh.shape[0]
             k = int(np.argmax(ll))

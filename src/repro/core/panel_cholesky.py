@@ -27,6 +27,13 @@ column-chunked variant `off_update="chunked"`.
 
 Everything is jnp (differentiable, GSPMD-shardable).  Numerics match the
 faithful tile engine (tests assert allclose against tile_cholesky.py).
+
+Named scopes (`jax.named_scope`, HLO `op_name` metadata only, DESIGN.md
+§13) mark each phase inside the compiled program: `geostat_loglik_step`
+holds `cov_build`, `factor` and `solve`, and inside the factorization
+each step's work is under `potrf`, `trsm_hi`, `trsm_lo`, `gather`,
+`update_hi` or `update_lo`.  The names are fixed, never per step, so a
+profiler trace sums each phase.
 """
 
 from __future__ import annotations
@@ -38,7 +45,6 @@ import jax
 import jax.numpy as jnp
 from jax.scipy.linalg import solve_triangular
 
-from .. import obs
 from ..covariance.matern import matern_covariance
 from .precision import PrecisionPolicy, lo_matmul
 
@@ -128,30 +134,19 @@ def panel_cholesky_banded(band, off, policy: PrecisionPolicy, *,
                              FLOP waste, exercised by the perf hillclimb);
                 "chunked" -- per-column-block lo GEMMs over the lower
                              trapezoid only (near-exact FLOPs).
+
+    Each step's work is under one of the named scopes `potrf`, `trsm_hi`,
+    `trsm_lo`, `gather`, `update_hi` and `update_lo`.
     """
-    # dispatch-boundary telemetry: no-op when disabled or when `band` is a
-    # tracer (the BatchEngine panel path jits/vmaps this whole function)
-    with obs.maybe_span("core.panel_cholesky", band,
-                        p=band.shape[0], nb=band.shape[-1],
-                        off_update=off_update) as sp:
-        band, off = _panel_cholesky_banded(band, off, policy,
-                                           off_update=off_update)
-        if sp is not obs.NULL_SPAN:
-            band.block_until_ready()
-            off.block_until_ready()
-        return band, off
-
-
-def _panel_cholesky_banded(band, off, policy: PrecisionPolicy, *,
-                           off_update: str):
     p, t, nb, _ = band.shape
     hi = policy.hi
     lo = off.dtype
 
     for k in range(p):
-        lkk = jnp.linalg.cholesky(band[k, 0])
-        band = band.at[k, 0].set(lkk)
-        lkk_lo = lkk.astype(lo)
+        with jax.named_scope("potrf"):
+            lkk = jnp.linalg.cholesky(band[k, 0])
+            band = band.at[k, 0].set(lkk)
+            lkk_lo = lkk.astype(lo)
 
         m_t = p - k - 1
         if m_t == 0:
@@ -159,54 +154,60 @@ def _panel_cholesky_banded(band, off, policy: PrecisionPolicy, *,
 
         # --- panel TRSMs -------------------------------------------------
         n_band_panel = min(t - 1, m_t)
-        for d in range(1, n_band_panel + 1):          # dtrsm (hi), tiles (k+d, k)
-            upd = _batched_trsm_right_lt(lkk, band[k + d, d][None], hi, hi)[0]
-            band = band.at[k + d, d].set(upd)
+        with jax.named_scope("trsm_hi"):
+            for d in range(1, n_band_panel + 1):  # dtrsm (hi), tiles (k+d, k)
+                upd = _batched_trsm_right_lt(lkk, band[k + d, d][None],
+                                             hi, hi)[0]
+                band = band.at[k + d, d].set(upd)
         if k + t <= p - 1:                            # strsm (lo)
-            sol = _batched_trsm_right_lt(lkk_lo, off[k + t:, k],
-                                         policy.solve_dtype, lo)
-            off = off.at[k + t:, k].set(sol)
+            with jax.named_scope("trsm_lo"):
+                sol = _batched_trsm_right_lt(lkk_lo, off[k + t:, k],
+                                             policy.solve_dtype, lo)
+                off = off.at[k + t:, k].set(sol)
 
         # --- gather the factored panel column as hi tiles ----------------
-        parts = [band[k + d, d][None] for d in range(1, n_band_panel + 1)]
-        if k + t <= p - 1:
-            parts.append(off[k + t:, k].astype(hi))
-        c_hi = jnp.concatenate(parts, axis=0)
+        with jax.named_scope("gather"):
+            parts = [band[k + d, d][None] for d in range(1, n_band_panel + 1)]
+            if k + t <= p - 1:
+                parts.append(off[k + t:, k].astype(hi))
+            c_hi = jnp.concatenate(parts, axis=0)
         # c_hi[m] = tile (k+1+m, k), shape (m_t, nb, nb)
 
         # --- hi band updates: sub-diagonals d = 0..t-1 (dsyrk/dgemm) -----
-        for d in range(0, min(t, m_t)):
-            lhs = c_hi[d:]
-            rhs = c_hi[:m_t - d]
-            upd = jnp.einsum("iab,icb->iac", lhs, rhs,
-                             preferred_element_type=hi)
-            band = band.at[k + 1 + d:, d].add(-upd.astype(hi))
+        with jax.named_scope("update_hi"):
+            for d in range(0, min(t, m_t)):
+                lhs = c_hi[d:]
+                rhs = c_hi[:m_t - d]
+                upd = jnp.einsum("iab,icb->iac", lhs, rhs,
+                                 preferred_element_type=hi)
+                band = band.at[k + 1 + d:, d].add(-upd.astype(hi))
 
         # --- lo off-band update (sgemm) ----------------------------------
-        c_lo = c_hi.astype(lo).reshape(m_t * nb, nb)
-        ii, jj = np.meshgrid(np.arange(k + 1, p), np.arange(k + 1, p),
-                             indexing="ij")
-        mask = jnp.asarray((ii - jj) >= t)[:, :, None, None]
-        if off_update == "square":
-            u = lo_matmul(c_lo, c_lo.T, policy)                  # (m, m) lo
-            u_t = u.reshape(m_t, nb, m_t, nb).transpose(0, 2, 1, 3)
-            blk = off[k + 1:, k + 1:]
-            off = off.at[k + 1:, k + 1:].set(
-                jnp.where(mask, (blk - u_t.astype(lo)), blk))
-        elif off_update == "chunked":
-            # exact lower trapezoid: for each target column-tile j, only
-            # rows i >= j + t receive the lo update.
-            c_lo_t = c_lo.reshape(m_t, nb, nb)
-            for j in range(k + 1, p - t):
-                rows = slice(j + t, p)                  # global tile rows
-                lhs = c_lo_t[j + t - k - 1:]            # tiles (j+t..p-1, k)
-                rhs = c_lo_t[j - k - 1]                 # tile (j, k)
-                upd = lo_matmul(lhs, jnp.broadcast_to(rhs.T[None],
-                                                      (lhs.shape[0], nb, nb)),
-                                policy)
-                off = off.at[rows, j].add(-upd.astype(lo))
-        else:
-            raise ValueError(off_update)
+        with jax.named_scope("gather"):
+            c_lo = c_hi.astype(lo).reshape(m_t * nb, nb)
+        with jax.named_scope("update_lo"):
+            ii, jj = np.meshgrid(np.arange(k + 1, p), np.arange(k + 1, p),
+                                 indexing="ij")
+            mask = jnp.asarray((ii - jj) >= t)[:, :, None, None]
+            if off_update == "square":
+                u = lo_matmul(c_lo, c_lo.T, policy)              # (m, m) lo
+                u_t = u.reshape(m_t, nb, m_t, nb).transpose(0, 2, 1, 3)
+                blk = off[k + 1:, k + 1:]
+                off = off.at[k + 1:, k + 1:].set(
+                    jnp.where(mask, (blk - u_t.astype(lo)), blk))
+            elif off_update == "chunked":
+                # exact lower trapezoid: for each target column-tile j,
+                # only rows i >= j + t receive the lo update.
+                c_lo_t = c_lo.reshape(m_t, nb, nb)
+                for j in range(k + 1, p - t):
+                    rows = slice(j + t, p)              # global tile rows
+                    lhs = c_lo_t[j + t - k - 1:]        # tiles (j+t..p-1, k)
+                    rhs = c_lo_t[j - k - 1]             # tile (j, k)
+                    upd = lo_matmul(lhs, jnp.broadcast_to(
+                        rhs.T[None], (lhs.shape[0], nb, nb)), policy)
+                    off = off.at[rows, j].add(-upd.astype(lo))
+            else:
+                raise ValueError(off_update)
     return band, off
 
 
@@ -251,13 +252,14 @@ def geostat_loglik_step(locs, z, theta, *, nb: int, policy: PrecisionPolicy,
     This is the unit the paper benchmarks ("time per iteration") and the
     function the geostat dry-run lowers on the production mesh.
     """
-    with obs.maybe_span("core.panel_loglik_step", locs, theta,
-                        n=locs.shape[0] if hasattr(locs, "shape") else None,
-                        nb=nb, mode=policy.mode):
-        band, off = build_banded_covariance(locs, theta, nb=nb, policy=policy,
-                                            nu_static=nu_static,
-                                            metric=metric, jitter=jitter)
+    with jax.named_scope("geostat_loglik_step"):
+        with jax.named_scope("cov_build"):
+            band, off = build_banded_covariance(
+                locs, theta, nb=nb, policy=policy, nu_static=nu_static,
+                metric=metric, jitter=jitter)
         t = min(policy.diag_thick, band.shape[0])
-        band, off = panel_cholesky_banded(band, off, policy,
-                                          off_update=off_update)
-        return banded_loglik(band, off, z, t)
+        with jax.named_scope("factor"):
+            band, off = panel_cholesky_banded(band, off, policy,
+                                              off_update=off_update)
+        with jax.named_scope("solve"):
+            return banded_loglik(band, off, z, t)

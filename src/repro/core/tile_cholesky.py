@@ -22,10 +22,16 @@ performance/distributed path lives in panel_cholesky.py.
 
 Also implements the DST (Diagonal-Super-Tile / independent blocks)
 covariance-tapering baseline of paper Sec. V-B.
+
+Inside jit each tile op carries a fixed named scope (HLO `op_name`
+metadata only, DESIGN.md §13): `convert` (the dlag2s storage casts and
+the final assembly), `potrf`, `trsm_hi`, `trsm_lo`, `update_hi`
+(dsyrk/dgemm) and `update_lo` (sgemm).
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax.scipy.linalg import solve_triangular
 
@@ -113,38 +119,52 @@ def _tile_cholesky_eager(a, nb: int, policy: PrecisionPolicy):
 
     # initial storage conversion (lines 2-6, dlag2s on off-band tiles)
     store = {}
-    for (i, j), t in tiles.items():
-        store[(i, j)] = t.astype(hi) if policy.in_band(i, j) else t.astype(tier(i, j))
+    with jax.named_scope("convert"):
+        for (i, j), t in tiles.items():
+            store[(i, j)] = t.astype(hi) if policy.in_band(i, j) \
+                else t.astype(tier(i, j))
 
     for k in range(p):
-        l_kk = _potrf(store[(k, k)], hi)          # line 8: dpotrf
-        store[(k, k)] = l_kk
-        l_kk_lo = l_kk.astype(lo)                 # line 9: dlag2s -> tmp
+        with jax.named_scope("potrf"):
+            l_kk = _potrf(store[(k, k)], hi)      # line 8: dpotrf
+            store[(k, k)] = l_kk
+            l_kk_lo = l_kk.astype(lo)             # line 9: dlag2s -> tmp
 
         for i in range(k + 1, p):                 # panel TRSMs
             if policy.in_band(i, k):              # line 12: dtrsm
-                store[(i, k)] = _trsm_right_lt(l_kk, store[(i, k)], hi, hi)
+                with jax.named_scope("trsm_hi"):
+                    store[(i, k)] = _trsm_right_lt(l_kk, store[(i, k)], hi,
+                                                   hi)
             else:                                 # line 14: strsm (+15 sconv2d)
                 t = tier(i, k)
-                store[(i, k)] = _trsm_right_lt(
-                    l_kk_lo, store[(i, k)].astype(lo), policy.solve_dtype, t)
+                with jax.named_scope("trsm_lo"):
+                    store[(i, k)] = _trsm_right_lt(
+                        l_kk_lo, store[(i, k)].astype(lo), policy.solve_dtype,
+                        t)
 
         for j in range(k + 1, p):                 # trailing update
-            a_jk_hi = store[(j, k)].astype(hi)    # sconv2d'd copy if off-band
-            a_jk_hi_t = jnp.swapaxes(a_jk_hi, -1, -2)
-            # line 19: dsyrk, always hi
-            store[(j, j)] = store[(j, j)] - a_jk_hi @ a_jk_hi_t
+            with jax.named_scope("update_hi"):
+                # sconv2d'd copy if off-band
+                a_jk_hi = store[(j, k)].astype(hi)
+                a_jk_hi_t = jnp.swapaxes(a_jk_hi, -1, -2)
+                # line 19: dsyrk, always hi
+                store[(j, j)] = store[(j, j)] - a_jk_hi @ a_jk_hi_t
             for i in range(j + 1, p):
                 if policy.in_band(i, j):          # line 25: dgemm
-                    a_ik = store[(i, k)].astype(hi)
-                    store[(i, j)] = store[(i, j)] - a_ik @ a_jk_hi_t
+                    with jax.named_scope("update_hi"):
+                        a_ik = store[(i, k)].astype(hi)
+                        store[(i, j)] = store[(i, j)] - a_ik @ a_jk_hi_t
                 else:                             # line 27: sgemm (lo storage)
                     t = tier(i, j)
-                    upd = lo_matmul(store[(i, k)], jnp.swapaxes(store[(j, k)], -1, -2),
-                                    policy, tier=lo)
-                    store[(i, j)] = (store[(i, j)].astype(lo) - upd).astype(t)
+                    with jax.named_scope("update_lo"):
+                        upd = lo_matmul(store[(i, k)],
+                                        jnp.swapaxes(store[(j, k)], -1, -2),
+                                        policy, tier=lo)
+                        store[(i, j)] = (store[(i, j)].astype(lo)
+                                         - upd).astype(t)
 
-    return assemble_lower(store, p, nb, hi)
+    with jax.named_scope("convert"):
+        return assemble_lower(store, p, nb, hi)
 
 
 def dst_cholesky(a, nb: int, diag_thick: int, hi=jnp.float32):

@@ -10,15 +10,19 @@ site when it is off.
 Design constraints (DESIGN.md §13):
 
   * Zero dependencies, stdlib only.  JAX is never imported here; the
-    `maybe_span` tracer guard imports it lazily at the call site's first
-    *enabled* use.
+    `maybe_span` tracer guard and the profiler annotation import it
+    lazily at the call site's first *enabled* use.
   * Near-zero cost when disabled: the module-level `span`/`inc`/`observe`
     helpers check one module global and return a shared no-op object.
     Nothing allocates, nothing locks.
-  * Instrumentation lives at dispatch boundaries only.  A span timed
-    inside jit-traced code would measure trace time once and then never
-    run again; `maybe_span(name, *arrays)` therefore degrades to the
-    no-op span when any guard array is a JAX tracer.
+  * Host spans live at dispatch boundaries only.  A span timed inside
+    jit-traced code would measure trace time once and then never run
+    again; `maybe_span(name, *arrays)` therefore degrades to the no-op
+    span when any guard array is a JAX tracer.  Inside jit the compiled
+    programs name their phases with `jax.named_scope` instead.
+  * An enabled module-level `span` also opens a
+    `jax.profiler.TraceAnnotation` of its name, so a profiler trace holds
+    the program's spans on the host plane, on the device ops' clock.
   * Spans nest: each recorder keeps a per-thread stack so every finished
     span knows its depth (the Chrome-trace bridge lays depths out as
     separate tracks) and unwinds correctly through exceptions.
@@ -108,22 +112,29 @@ class SpanRecord:
 
 
 class _Span:
-    """Context manager that records a SpanRecord into its recorder."""
+    """Context manager that records a SpanRecord into its recorder; with
+    `annotate`, also a profiler annotation of the same name."""
 
-    __slots__ = ("_rec", "name", "attrs", "_start", "_depth")
+    __slots__ = ("_rec", "name", "attrs", "_start", "_depth", "_note")
 
-    def __init__(self, rec: "Recorder", name: str, attrs: dict):
+    def __init__(self, rec: "Recorder", name: str, attrs: dict,
+                 annotate: bool = False):
         self._rec = rec
         self.name = name
         self.attrs = attrs
+        self._note = _annotation(name) if annotate else None
 
     def __enter__(self):
+        if self._note is not None:
+            self._note.__enter__()
         self._depth = self._rec._push()
         self._start = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         end = time.perf_counter()
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
         self._rec._pop()
         self._rec._finish(SpanRecord(
             name=self.name, start=self._start, end=end,
@@ -279,10 +290,18 @@ class recording:
 # ---------------------------------------------------------------------------
 
 def span(name: str, **attrs):
-    """Nestable wall-clock timer; no-op (shared singleton) when disabled."""
+    """Nestable wall-clock timer, also written to a running profiler
+    trace; no-op (shared singleton) when disabled."""
     if not _ENABLED:
         return NULL_SPAN
-    return _RECORDER.span(name, **attrs)
+    return _Span(_RECORDER, name, attrs, annotate=True)
+
+
+def _annotation(name: str):
+    # lazy: only reached when telemetry is enabled
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
 
 
 def _is_tracing(arrays) -> bool:
@@ -304,7 +323,7 @@ def maybe_span(name: str, *guard_arrays, **attrs):
         return NULL_SPAN
     if guard_arrays and _is_tracing(guard_arrays):
         return NULL_SPAN
-    return _RECORDER.span(name, **attrs)
+    return _Span(_RECORDER, name, attrs, annotate=True)
 
 
 def inc(name: str, n: float = 1) -> None:

@@ -6,7 +6,9 @@ no chip time.  The topology is described inside a module fixture, never at
 import, because only one process at a time may load the TPU library.
 """
 
+import contextlib
 import os
+import re
 from functools import partial
 
 import jax
@@ -17,6 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from repro.core import PrecisionPolicy
 from repro.core.distributed import (build_covariance_distributed,
                                     geostat_loglik_distributed)
+from repro.core.kriging import krige
 from repro.core.panel_cholesky import geostat_loglik_step
 from repro.launch.mesh import make_geostat_mesh
 from repro.models.sharding import set_activation_mesh
@@ -86,3 +89,38 @@ def test_distributed_loglik_is_sharded_over_v5e_2x2(topo, version):
     # the row-sharded inputs alone would leave each device n/2 full rows
     assert off_sharding.shard_shape((N, N)) == (N // 2, N // 2)
     assert 0 < _bytes(compiled) < V5E_HBM_BYTES
+
+
+def _program_text(compiled) -> str:
+    """A compiled program's HLO without `op_name` metadata and without the
+    source-location tables that the metadata points into."""
+    lines = [line for line in compiled.as_text().splitlines()
+             if line.startswith(("HloModule", "%", "ENTRY", " ", "}"))]
+    return re.sub(r", metadata=\{[^}]*\}", "", "\n".join(lines))
+
+
+@pytest.mark.parametrize("which", ["eval", "krige"])
+def test_named_scopes_change_no_operation_on_v5e(topo, monkeypatch, which):
+    """The named scopes are metadata alone for the chip's compiler too."""
+    one = SingleDeviceSharding(topo.devices[0])
+    spec = partial(jax.ShapeDtypeStruct, sharding=one)
+    policy = PrecisionPolicy.tpu(2)
+    if which == "eval":
+        fn = partial(geostat_loglik_step, nb=NB, policy=policy,
+                     nu_static=0.5)
+        args = (spec((N, 2), jnp.float32), spec((N,), jnp.float32),
+                spec((2,), jnp.float32))
+    else:
+        def fn(locs, z, new, theta):
+            return krige(locs[:N], z[:N], locs[new], theta, policy, nb=NB,
+                         nu_static=0.5)
+        args = (spec((N + NB, 2), jnp.float32), spec((N + NB,), jnp.float32),
+                spec((NB,), jnp.int32), spec((2,), jnp.float32))
+    scoped = jax.jit(fn).lower(*args).compile()
+    assert "/factor/potrf/" in scoped.as_text()
+    jax.clear_caches()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = jax.jit(fn).lower(*args).compile()
+    assert "/factor/potrf/" not in plain.as_text()
+    assert _program_text(scoped) == _program_text(plain)

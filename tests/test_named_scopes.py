@@ -1,0 +1,119 @@
+"""Named scopes of the compiled likelihood step and kriging (DESIGN.md §13).
+
+The scopes are HLO `op_name` metadata and nothing else: with `jax.named_scope`
+replaced by a null context, the lowered and the compiled programs are the
+same once metadata and source locations are left out.  Every phase name
+is present in the compiled program's metadata, so a profiler trace can
+sum device time per phase.
+"""
+
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.core.kriging import krige
+from repro.core.panel_cholesky import geostat_loglik_step
+from repro.core.precision import PrecisionPolicy
+
+N, NB = 256, 32
+POLICY = PrecisionPolicy(mode="mixed", hi=jnp.float32, lo=jnp.bfloat16,
+                         diag_thick=2, solve_dtype=jnp.float32,
+                         accum_dtype=jnp.float32)
+
+PANEL_PHASES = ("potrf", "trsm_hi", "trsm_lo", "gather", "update_hi",
+                "update_lo")
+TILE_PHASES = ("potrf", "trsm_hi", "trsm_lo", "update_hi", "update_lo",
+               "convert")
+SCOPES = {
+    "eval": ["geostat_loglik_step/cov_build", "geostat_loglik_step/factor",
+             "geostat_loglik_step/solve"]
+    + [f"geostat_loglik_step/factor/{p}" for p in PANEL_PHASES],
+    "krige": ["krige/cov_build", "krige/factor", "krige/solve"]
+    + [f"krige/factor/{p}" for p in TILE_PHASES],
+}
+
+
+def _inputs():
+    rng = np.random.default_rng(3)
+    locs = jnp.asarray(rng.random((N, 2)), jnp.float32)
+    z = jnp.asarray(rng.standard_normal(N), jnp.float32)
+    return locs, z, jnp.asarray([1.0, 0.03], jnp.float32)
+
+
+def _program(which, off_update="square"):
+    """The step or the kriging request as the benchmark jits them, with
+    the field, theta and (kriging) the split as arguments."""
+    locs, z, theta = _inputs()
+    if which == "eval":
+        def fn(locs, z, theta):
+            return geostat_loglik_step(locs, z, theta, nb=NB, policy=POLICY,
+                                       nu_static=0.5, jitter=1e-6,
+                                       off_update=off_update)
+        return fn, (locs, z, theta)
+
+    def fn(locs, z, obs, new, theta):
+        return krige(locs[obs], z[obs], locs[new], theta, POLICY, nb=NB,
+                     nu_static=0.5, jitter=1e-6)
+    new = jnp.arange(N - NB, N, dtype=jnp.int32)
+    obs = jnp.arange(0, N - NB, dtype=jnp.int32)
+    return fn, (locs, z, obs, new, theta)
+
+
+def _no_metadata(hlo: str) -> str:
+    """The compiled program without `op_name` metadata and without the
+    tables of source locations that the metadata points into."""
+    lines = [line for line in hlo.splitlines()
+             if line.startswith(("HloModule", "%", "ENTRY", " ", "}"))]
+    return re.sub(r", metadata=\{[^}]*\}", "", "\n".join(lines))
+
+
+CASES = [("eval", "square"), ("eval", "chunked"), ("krige", None)]
+
+
+@pytest.mark.parametrize("which,off_update", CASES)
+def test_scopes_change_no_operation(monkeypatch, which, off_update):
+    fn, args = _program(which, off_update or "square")
+    scoped = jax.jit(fn).lower(*args)
+    scoped_hlo = scoped.compile().as_text()
+    # JAX would hand back the executable it compiled above for a program
+    # that differs from it in metadata alone
+    jax.clear_caches()
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        plain = jax.jit(fn).lower(*args)
+        plain_hlo = plain.compile().as_text()
+    # as_text() prints no debug info: locations, where the scopes live, stay out
+    assert scoped.as_text() == plain.as_text()
+    plain_names = " ".join(re.findall(r'op_name="([^"]*)"', plain_hlo))
+    assert "geostat_loglik_step/" not in plain_names
+    assert "krige/" not in plain_names
+    assert _no_metadata(scoped_hlo) == _no_metadata(plain_hlo)
+
+
+@pytest.mark.parametrize("which,off_update", CASES)
+def test_every_scope_is_in_the_compiled_metadata(which, off_update):
+    fn, args = _program(which, off_update or "square")
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for scope in SCOPES[which]:
+        assert any(f"/{scope}/" in name for name in op_names), scope
+
+
+def test_scope_names_are_fixed_not_per_step():
+    fn, args = _program("eval")
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    under = {name.split("/factor/")[1].split("/")[0] for name in op_names
+             if "/factor/" in name}
+    assert under == set(PANEL_PHASES)
+
+
+def test_scoped_step_gives_the_same_answer_eagerly_and_jitted():
+    fn, args = _program("eval")
+    eager = float(fn(*args))
+    assert np.isfinite(eager)
+    assert float(jax.jit(fn)(*args)) == pytest.approx(eager, rel=1e-5)

@@ -3,6 +3,7 @@ exporter round-trips, the merged Chrome trace, kernel-time calibration, and
 the disabled-mode overhead guard.
 """
 
+import contextlib
 import json
 import threading
 import time
@@ -144,6 +145,83 @@ def test_maybe_span_noops_under_jit():
             jnp.asarray(a)).block_until_ready()              # traced: no-op
     names = [s.name for s in rec.spans]
     assert names.count("core.tile_cholesky") == 1
+
+
+# ---------------------------------------------------------------------------
+# program spans on the profiler's clock; the MLE loop's spans
+# ---------------------------------------------------------------------------
+
+def _profiled_host_events(tmp_path, body):
+    """Names of the host events a CPU profiler trace holds around `body`."""
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    return {ev.name for plane in data.planes if plane.name.startswith("/host")
+            for line in plane.lines for ev in line.events}
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_span_writes_a_profiler_host_event_only_when_enabled(tmp_path,
+                                                             enabled):
+    def body():
+        with obs.recording() if enabled else contextlib.nullcontext():
+            with obs.span("test.annotated"):
+                jnp.ones(4).block_until_ready()
+
+    names = _profiled_host_events(tmp_path, body)
+    assert ("test.annotated" in names) == enabled
+
+
+def _peaked(theta):
+    """A log-likelihood peaked at theta = (1.5, 0.2), for the optimizer."""
+    t = jnp.log(theta) - jnp.log(jnp.array([1.5, 0.2]))
+    return -jnp.sum(t * t)
+
+
+def test_mle_spans_count_iterations_and_evaluations():
+    from repro.core.mle import fit_mle
+
+    with obs.recording() as rec:
+        res = fit_mle(_peaked, [1.0, 0.1], max_iters=60, jit=False)
+    names = [s.name for s in rec.spans]
+    assert names.count("mle.fit") == 1
+    assert names.count("mle.iter") == res.n_iters > 1
+    assert names.count("mle.eval") == res.n_evals
+    assert names.count("mle.theta") == res.n_evals
+    hist = rec.snapshot()["histograms"]
+    assert hist["mle.eval"]["count"] == res.n_evals
+    assert hist["mle.iter"]["count"] == res.n_iters
+    assert not any(k.startswith("mle.eval_seconds") for k in hist)
+    # the initial simplex (d + 1 points) is evaluated before the first
+    # iteration, inside mle.fit; every other evaluation inside an mle.iter
+    depths = [s.depth for s in rec.spans if s.name == "mle.eval"]
+    assert depths.count(1) == 3 and depths.count(2) == res.n_evals - 3
+
+
+def test_mle_batched_calls_are_mle_eval_batch_spans():
+    from repro.core.mle import fit_mle, fit_mle_grid
+
+    batched = jax.vmap(_peaked)
+    with obs.recording() as rec:
+        fit_mle_grid(batched, [(0.5, 3.0), (0.05, 0.8)], num=4, refine=3)
+    assert [s.name for s in rec.spans].count("mle.eval_batch") == 3
+    with obs.recording() as rec:
+        res = fit_mle(None, [1.0, 0.1], max_iters=30,
+                      batched_loglik_fn=batched)
+    names = [s.name for s in rec.spans]
+    assert names.count("mle.iter") == res.n_iters
+    assert names.count("mle.eval") == 0
+    # one batched call per iteration that did not converge, plus the
+    # initial simplex and one per shrink
+    assert res.n_iters <= names.count("mle.eval_batch") <= 2 * res.n_iters
+    assert not any(k.startswith("mle.eval_batch_seconds")
+                   for k in rec.snapshot()["histograms"])
 
 
 # ---------------------------------------------------------------------------
