@@ -17,13 +17,17 @@ Per step k (all slices static because the loop is unrolled):
   2. hi TRSM on the <= t-1 band panel tiles               (dtrsm)
      lo TRSM on the off panel tiles                       (strsm)
   3. hi batched sub-diagonal updates d = 0..t-1           (dsyrk/dgemm)
-  4. one big lo GEMM U = C_lo C_lo^T applied to the off-band region
-     under a static tile mask                             (sgemm)
+  4. lo GEMMs on the off-band tiles (i, j) with j + t <= i,
+     the step's lower trapezoid                           (sgemm)
 
-Step 4 computes the full (m x m) square -- ~2x the FLOPs of the needed
-lower trapezoid.  That waste is deliberate v1 behaviour: it is the first
-hypothesis of the §Perf hillclimb (see EXPERIMENTS.md), fixed by the
-column-chunked variant `off_update="chunked"`.
+Step 4 (`off_update="chunked"`, the default) touches the trapezoid's
+tiles alone, in place.  On a TPU, for bf16 tiles whose size is a
+multiple of 128, it is one Pallas call per step
+(`kernels/lo_trailing_update`); elsewhere one batched GEMM per tile
+column.  `off_update="square"` computes the whole (m x m) product
+C_lo C_lo^T and keeps the trapezoid under a static tile mask: ~3x the
+trapezoid's FLOPs at p = 32, plus a transposed copy and a whole-block
+select; `BatchEngine`, which vmaps the step, asks for it.
 
 Everything is jnp (differentiable, GSPMD-shardable).  Numerics match the
 faithful tile engine (tests assert allclose against tile_cholesky.py).
@@ -46,6 +50,8 @@ import jax.numpy as jnp
 from jax.scipy.linalg import solve_triangular
 
 from ..covariance.matern import matern_covariance
+from ..kernels.lo_trailing_update.lo_trailing_update import (
+    lo_trailing_update_pallas)
 from .precision import PrecisionPolicy, lo_matmul
 
 
@@ -126,14 +132,55 @@ def _batched_trsm_right_lt(l, a, exec_dtype, out_dtype):
     return jnp.swapaxes(x, -1, -2).astype(out_dtype)
 
 
+def _lo_update_loop(c, off, *, k: int, t: int, policy: PrecisionPolicy):
+    """Step k's lo update, one batched GEMM per target tile column j: only
+    rows i >= j + t receive c[i] c[j]^T.  c[m] = tile (k+1+m, k)."""
+    p, nb = off.shape[0], off.shape[-1]
+    for j in range(k + 1, p - t):
+        lhs = c[j + t - k - 1:]                 # tiles (j+t..p-1, k)
+        rhs = c[j - k - 1]                      # tile (j, k)
+        upd = lo_matmul(lhs, jnp.broadcast_to(
+            rhs.T[None], (lhs.shape[0], nb, nb)), policy)
+        off = off.at[j + t:, j].add(-upd.astype(off.dtype))
+    return off
+
+
+def _lo_update(c, off, *, k: int, t: int, policy: PrecisionPolicy):
+    """Step k's lo update on its lower trapezoid, in place.
+
+    On a TPU, bf16 tiles whose size is a multiple of 128 take the Pallas
+    kernel, one call; every other platform, dtype or size takes the loop.
+    The platform is chosen when the program is lowered, so a compile for
+    a described TPU takes the kernel too.  Derivatives are the loop's.
+    """
+    p, nb = off.shape[0], off.shape[-1]
+    if k + 1 + t > p - 1:                       # empty trapezoid
+        return off
+    loop = partial(_lo_update_loop, k=k, t=t, policy=policy)
+    if off.dtype != jnp.bfloat16 or nb % 128:
+        return loop(c, off)
+    kernel = partial(lo_trailing_update_pallas, k=k, t=t,
+                     accum_dtype=policy.accum_dtype, interpret=False)
+
+    @jax.custom_jvp
+    def update(c, off):
+        return jax.lax.platform_dependent(c, off, tpu=kernel, default=loop)
+
+    @update.defjvp
+    def _(primals, tangents):
+        return update(*primals), jax.jvp(loop, primals, tangents)[1]
+
+    return update(c, off)
+
+
 def panel_cholesky_banded(band, off, policy: PrecisionPolicy, *,
-                          off_update: str = "square"):
+                          off_update: str = "chunked"):
     """Factor the banded-storage SPD matrix in place. Returns (band, off).
 
-    off_update: "square"  -- one full m x m lo GEMM per step (v1; ~2x lo
-                             FLOP waste, exercised by the perf hillclimb);
-                "chunked" -- per-column-block lo GEMMs over the lower
-                             trapezoid only (near-exact FLOPs).
+    off_update: "chunked" -- the lo update on each step's lower trapezoid
+                             only (`_lo_update`: exact FLOPs, in place);
+                "square"  -- one full m x m lo GEMM per step under a tile
+                             mask (~3x the FLOPs at p = 32; vmappable).
 
     Each step's work is under one of the named scopes `potrf`, `trsm_hi`,
     `trsm_lo`, `gather`, `update_hi` and `update_lo`.
@@ -186,26 +233,18 @@ def panel_cholesky_banded(band, off, policy: PrecisionPolicy, *,
         with jax.named_scope("gather"):
             c_lo = c_hi.astype(lo).reshape(m_t * nb, nb)
         with jax.named_scope("update_lo"):
-            ii, jj = np.meshgrid(np.arange(k + 1, p), np.arange(k + 1, p),
-                                 indexing="ij")
-            mask = jnp.asarray((ii - jj) >= t)[:, :, None, None]
             if off_update == "square":
+                ii, jj = np.meshgrid(np.arange(k + 1, p), np.arange(k + 1, p),
+                                     indexing="ij")
+                mask = jnp.asarray((ii - jj) >= t)[:, :, None, None]
                 u = lo_matmul(c_lo, c_lo.T, policy)              # (m, m) lo
                 u_t = u.reshape(m_t, nb, m_t, nb).transpose(0, 2, 1, 3)
                 blk = off[k + 1:, k + 1:]
                 off = off.at[k + 1:, k + 1:].set(
                     jnp.where(mask, (blk - u_t.astype(lo)), blk))
             elif off_update == "chunked":
-                # exact lower trapezoid: for each target column-tile j,
-                # only rows i >= j + t receive the lo update.
-                c_lo_t = c_lo.reshape(m_t, nb, nb)
-                for j in range(k + 1, p - t):
-                    rows = slice(j + t, p)              # global tile rows
-                    lhs = c_lo_t[j + t - k - 1:]        # tiles (j+t..p-1, k)
-                    rhs = c_lo_t[j - k - 1]             # tile (j, k)
-                    upd = lo_matmul(lhs, jnp.broadcast_to(
-                        rhs.T[None], (lhs.shape[0], nb, nb)), policy)
-                    off = off.at[rows, j].add(-upd.astype(lo))
+                off = _lo_update(c_lo.reshape(m_t, nb, nb), off, k=k, t=t,
+                                 policy=policy)
             else:
                 raise ValueError(off_update)
     return band, off
@@ -246,7 +285,7 @@ def banded_loglik(band, off, z, t: int):
 
 def geostat_loglik_step(locs, z, theta, *, nb: int, policy: PrecisionPolicy,
                         nu_static=None, metric="euclidean", jitter=1e-6,
-                        off_update: str = "square"):
+                        off_update: str = "chunked"):
     """One full likelihood evaluation: cov-gen -> factor -> solve -> ll.
 
     This is the unit the paper benchmarks ("time per iteration") and the

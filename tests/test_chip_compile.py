@@ -6,6 +6,7 @@ no chip time.  The topology is described inside a module fixture, never at
 import, because only one process at a time may load the TPU library.
 """
 
+import base64
 import contextlib
 import os
 import re
@@ -91,12 +92,66 @@ def test_distributed_loglik_is_sharded_over_v5e_2x2(topo, version):
     assert 0 < _bytes(compiled) < V5E_HBM_BYTES
 
 
+def _step(topo, policy, n=N, nb=NB, off_update="chunked"):
+    one = SingleDeviceSharding(topo.devices[0])
+    spec = partial(jax.ShapeDtypeStruct, sharding=one)
+    fn = jax.jit(partial(geostat_loglik_step, nb=nb, policy=policy,
+                         nu_static=0.5, off_update=off_update))
+    return fn.lower(spec((n, 2), jnp.float32), spec((n,), jnp.float32),
+                    spec((2,), jnp.float32)).compile()
+
+
+def _update_lo_ops(compiled) -> list:
+    """The compiled program's instructions under the `update_lo` scope."""
+    return [line for line in compiled.as_text().splitlines()
+            if "/factor/update_lo/" in line and " = " in line]
+
+
+def test_lo_update_is_one_kernel_per_step_on_v5e(topo):
+    """bf16 tiles of 512: each step whose trapezoid is not empty (k = 0
+    alone at p = 4, t = 2) makes one kernel call and no GEMM of its own,
+    and the program holds no more temporaries than the square's."""
+    policy = PrecisionPolicy.tpu(2)
+    compiled = _step(topo, policy)
+    ops = _update_lo_ops(compiled)
+    calls = [op for op in ops if 'custom_call_target="tpu_custom_call"' in op]
+    assert len(calls) == 1
+    assert all("/update_lo/" in op and "lo_trailing_update" in op
+               for op in calls)
+    assert not [op for op in ops if re.search(r" (dot|convolution)\(", op)]
+    square = _step(topo, policy, off_update="square")
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            <= square.memory_analysis().temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("policy,nb", [
+    (PrecisionPolicy(mode="mixed", hi=jnp.float32, lo=jnp.float32,
+                     diag_thick=2), NB),
+    (PrecisionPolicy.tpu(2), 64)], ids=["f32_lo", "nb64"])
+def test_lo_update_takes_the_loop_off_bf16_or_128_on_v5e(topo, policy, nb):
+    ops = _update_lo_ops(_step(topo, policy, n=8 * nb, nb=nb))
+    assert ops and not [op for op in ops if "tpu_custom_call" in op]
+
+
+def _kernel_text(match) -> str:
+    """A Pallas kernel's serialized Mosaic module, printed without its
+    source locations, which hold the named scopes of the call around it."""
+    from jax.extend.mlir import ir
+    ctx = ir.Context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        module = ir.Module.parse(base64.b64decode(match.group(1)))
+        return module.operation.get_asm(enable_debug_info=False)
+
+
 def _program_text(compiled) -> str:
-    """A compiled program's HLO without `op_name` metadata and without the
-    source-location tables that the metadata points into."""
+    """A compiled program's HLO without `op_name` metadata, without the
+    source-location tables that the metadata points into, and with each
+    kernel's body printed without its locations."""
     lines = [line for line in compiled.as_text().splitlines()
              if line.startswith(("HloModule", "%", "ENTRY", " ", "}"))]
-    return re.sub(r", metadata=\{[^}]*\}", "", "\n".join(lines))
+    text = re.sub(r", metadata=\{[^}]*\}", "", "\n".join(lines))
+    return re.sub(r'"body":"([A-Za-z0-9+/=]+)"', _kernel_text, text)
 
 
 @pytest.mark.parametrize("which", ["eval", "krige"])

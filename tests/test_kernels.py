@@ -14,6 +14,10 @@ from repro.kernels.blocked_potrf.ops import potrf
 from repro.kernels.blocked_potrf.ref import potrf_ref
 from repro.kernels.mp_attention.ops import banded_decode_attention, quantize_kv
 from repro.kernels.mp_attention.ref import banded_decode_attention_ref
+from repro.kernels.lo_trailing_update.ops import lo_trailing_update
+from repro.kernels.lo_trailing_update.ref import lo_trailing_update_ref
+from repro.core import PrecisionPolicy
+from repro.core.panel_cholesky import _lo_update, _lo_update_loop
 from conftest import spd_matrix
 
 
@@ -105,6 +109,69 @@ def test_potrf_batched():
         np.testing.assert_allclose(np.asarray(out[i]),
                                    np.asarray(potrf_ref(a[i])),
                                    rtol=5e-4, atol=5e-4)
+
+
+# -------------------------- lo_trailing_update ------------------------
+
+LO_P, LO_NB = 6, 128
+
+
+def _lo_step(t, k):
+    """Step k's panel c and a full off-band storage, both bf16."""
+    rng = np.random.default_rng(10 * t + k)
+    off = jnp.asarray(rng.standard_normal((LO_P, LO_P, LO_NB, LO_NB)),
+                      jnp.bfloat16)
+    c = jnp.asarray(rng.standard_normal((LO_P - k - 1, LO_NB, LO_NB)) / 8,
+                    jnp.bfloat16)
+    return c, off
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint16)
+
+
+@pytest.mark.parametrize("t", [1, 2, 3])
+@pytest.mark.parametrize("k", range(LO_P - 1))
+def test_lo_trailing_update_matches_ref_and_loop(t, k):
+    """Bitwise equal to the oracle and to the panel sweep's einsum loop
+    (same operands, f32 accumulation, one bf16 rounding of the product,
+    one of the difference), at every step, the last ones (m_t <= t, an
+    empty trapezoid) included."""
+    c, off = _lo_step(t, k)
+    out = lo_trailing_update(c, off, k=k, t=t)
+    ref = lo_trailing_update_ref(c, off, k=k, t=t)
+    loop = _lo_update_loop(c, off, k=k, t=t, policy=PrecisionPolicy.tpu(t))
+    assert out.dtype == off.dtype and out.shape == off.shape
+    np.testing.assert_array_equal(_bits(out), _bits(ref))
+    np.testing.assert_array_equal(_bits(out), _bits(loop))
+
+
+@pytest.mark.parametrize("t", [1, 2, 3])
+@pytest.mark.parametrize("k", range(LO_P - 1))
+def test_lo_trailing_update_leaves_other_tiles_alone(t, k):
+    """The band, the upper triangle and the columns <= k keep their bits;
+    every trapezoid tile changes."""
+    c, off = _lo_step(t, k)
+    out = _bits(lo_trailing_update(c, off, k=k, t=t))
+    before = _bits(off)
+    for i in range(LO_P):
+        for j in range(LO_P):
+            touched = j >= k + 1 and i - j >= t
+            assert np.array_equal(out[i, j], before[i, j]) != touched, (i, j)
+
+
+def test_lo_update_takes_the_einsum_loop_on_cpu():
+    """Off the TPU the step's lo update is the loop: no kernel in the
+    CPU program, the same bits, and derivatives through it."""
+    t, k = 1, 0
+    c, off = _lo_step(t, k)
+    pol = PrecisionPolicy.tpu(t)
+    fn = lambda c, off: _lo_update(c, off, k=k, t=t, policy=pol)
+    assert "tpu_custom_call" not in jax.jit(fn).lower(c, off).as_text()
+    np.testing.assert_array_equal(
+        _bits(fn(c, off)), _bits(_lo_update_loop(c, off, k=k, t=t, policy=pol)))
+    g = jax.grad(lambda c: jnp.sum(fn(c, off).astype(jnp.float32)))(c)
+    assert g.shape == c.shape and bool(jnp.all(jnp.isfinite(g)))
 
 
 # ---------------------------- mp_attention ----------------------------

@@ -9,6 +9,7 @@ sum device time per phase.
 
 import contextlib
 import re
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -110,6 +111,30 @@ def test_scope_names_are_fixed_not_per_step():
     under = {name.split("/factor/")[1].split("/")[0] for name in op_names
              if "/factor/" in name}
     assert under == set(PANEL_PHASES)
+
+
+def test_lo_update_kernel_carries_the_update_lo_scope():
+    """Lowered for a TPU, the step's lo update is the Pallas kernel, one
+    call per step whose trapezoid is not empty (k = 0 alone at p = 4,
+    t = 2), and the call's location, which becomes its `op_name`, names
+    `geostat_loglik_step/factor/update_lo`.  Lowered for the CPU, the
+    same step holds no kernel: the einsum loop."""
+    nb = 128
+    fn = partial(geostat_loglik_step, nb=nb, policy=POLICY, nu_static=0.5,
+                 jitter=1e-6)
+    args = (jnp.zeros((4 * nb, 2), jnp.float32),
+            jnp.zeros((4 * nb,), jnp.float32), jnp.ones((2,), jnp.float32))
+    tpu = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    text = tpu.as_text(debug_info=True)
+    calls = [line for line in text.splitlines()
+             if "stablehlo.custom_call @tpu_custom_call" in line]
+    assert len(calls) == 1
+    loc = re.search(r"loc\((#loc\d+)\)\s*$", calls[0]).group(1)
+    name = re.search(rf'^{loc} = loc\("([^"]*)"', text, re.M).group(1)
+    assert "/geostat_loglik_step/factor/update_lo/" in name
+    assert name.endswith("lo_trailing_update/pallas_call")
+    cpu = jax.jit(fn).lower(*args).as_text()
+    assert "tpu_custom_call" not in cpu
 
 
 def test_scoped_step_gives_the_same_answer_eagerly_and_jitted():
