@@ -20,6 +20,12 @@ touches the whole matrix).  That is the *baseline* the §Perf hillclimb
 attacks: `version="aligned"` shrinks the row range to the 16-tile-aligned
 boundary (static per step, still shard-aligned), cutting the waste to
 ~1.5x; column pruning (v3) gets ~1.15x.  See EXPERIMENTS.md §Perf.
+
+Named scopes (`jax.named_scope`, HLO `op_name` metadata only, as in
+panel_cholesky.py) mirror the one-chip step's: `geostat_loglik_distributed`
+holds `cov_build`, `factor` and `solve`, and each step of the `fori`
+sweep's body is under `potrf`, `trsm_hi`, `trsm_lo`, `gather`,
+`update_hi` or `update_lo`.
 """
 
 from __future__ import annotations
@@ -223,52 +229,59 @@ def _panel_cholesky_fori(off, band, policy: PrecisionPolicy):
 
     def step(k, carry):
         off, band = carry
-        lkk = jnp.linalg.cholesky(band[k, 0])
-        band = band.at[k, 0].set(lkk)
-        lkk_lo = lkk.astype(lo)
+        with jax.named_scope("potrf"):
+            lkk = jnp.linalg.cholesky(band[k, 0])
+            band = band.at[k, 0].set(lkk)
+            lkk_lo = lkk.astype(lo)
 
         # hi band-panel TRSMs (traced index, clamped + validity-masked)
-        for d in range(1, t):
-            idx = jnp.minimum(k + d, p - 1)
-            tile = band[idx, d]
-            upd = solve_triangular(lkk, tile.T, lower=True).T
-            valid = (k + d) < p
-            band = band.at[idx, d].set(jnp.where(valid, upd, tile))
+        with jax.named_scope("trsm_hi"):
+            for d in range(1, t):
+                idx = jnp.minimum(k + d, p - 1)
+                tile = band[idx, d]
+                upd = solve_triangular(lkk, tile.T, lower=True).T
+                valid = (k + d) < p
+                band = band.at[idx, d].set(jnp.where(valid, upd, tile))
 
         # lo panel TRSM over the full masked column
-        col = jax.lax.dynamic_slice(off, (0, k * nb), (n, nb))
-        col = _c_r2(col.astype(policy.solve_dtype))
-        sol = solve_triangular(lkk_lo.astype(policy.solve_dtype), col.T,
-                               lower=True).T
-        row_mask = (ii >= k + t)[:, None]
-        col_new = jnp.where(row_mask, sol, col).astype(lo)
-        off = jax.lax.dynamic_update_slice(off, _c_r2(col_new), (0, k * nb))
+        with jax.named_scope("trsm_lo"):
+            col = jax.lax.dynamic_slice(off, (0, k * nb), (n, nb))
+            col = _c_r2(col.astype(policy.solve_dtype))
+            sol = solve_triangular(lkk_lo.astype(policy.solve_dtype), col.T,
+                                   lower=True).T
+            row_mask = (ii >= k + t)[:, None]
+            col_new = jnp.where(row_mask, sol, col).astype(lo)
+            off = jax.lax.dynamic_update_slice(off, _c_r2(col_new),
+                                               (0, k * nb))
 
         # assemble panel column: off rows (>= k+t) + hi band rows
-        c_lo = jnp.where(row_mask, col_new, 0.0)
-        for d in range(1, t):
-            idx = jnp.minimum(k + d, p - 1)
-            cur = jax.lax.dynamic_slice(c_lo, (idx * nb, 0), (nb, nb))
-            tile = jnp.where((k + d) < p, band[idx, d].astype(lo), cur)
-            c_lo = jax.lax.dynamic_update_slice(c_lo, tile, (idx * nb, 0))
-        c_lo = _c_r2(c_lo)                       # rows <= k are zero
+        with jax.named_scope("gather"):
+            c_lo = jnp.where(row_mask, col_new, 0.0)
+            for d in range(1, t):
+                idx = jnp.minimum(k + d, p - 1)
+                cur = jax.lax.dynamic_slice(c_lo, (idx * nb, 0), (nb, nb))
+                tile = jnp.where((k + d) < p, band[idx, d].astype(lo), cur)
+                c_lo = jax.lax.dynamic_update_slice(c_lo, tile, (idx * nb, 0))
+            c_lo = _c_r2(c_lo)                   # rows <= k are zero
 
         # hi sub-diagonal updates, roll-aligned (see unrolled variant)
-        c_t = constrain(c_lo.reshape(p, nb, nb).astype(hi),
-                        "geo_rows geo_cols .")
-        for d in range(t):
-            shifted = jnp.roll(c_t, d, axis=0) if d else c_t
-            upd = jnp.einsum("iab,icb->iac", c_t, shifted,
-                             preferred_element_type=hi)
-            wrap_ok = (row_tile >= d)[:, None, None]
-            band = band.at[:, d].add(-jnp.where(wrap_ok, upd, 0.0))
+        with jax.named_scope("update_hi"):
+            c_t = constrain(c_lo.reshape(p, nb, nb).astype(hi),
+                            "geo_rows geo_cols .")
+            for d in range(t):
+                shifted = jnp.roll(c_t, d, axis=0) if d else c_t
+                upd = jnp.einsum("iab,icb->iac", c_t, shifted,
+                                 preferred_element_type=hi)
+                wrap_ok = (row_tile >= d)[:, None, None]
+                band = band.at[:, d].add(-jnp.where(wrap_ok, upd, 0.0))
 
         # lo off-band trailing update, full-width masked
-        u = lo_matmul(c_lo, c_lo.T, policy)
-        u = _c_r2(u)
-        mask = ((ii[:, None] - ii[None, :] >= t)
-                & (ii[None, :] > k) & (ii[:, None] > k))
-        off = _c_r2(jnp.where(mask, (off - u.astype(lo)), off))
+        with jax.named_scope("update_lo"):
+            u = lo_matmul(c_lo, c_lo.T, policy)
+            u = _c_r2(u)
+            mask = ((ii[:, None] - ii[None, :] >= t)
+                    & (ii[None, :] > k) & (ii[:, None] > k))
+            off = _c_r2(jnp.where(mask, (off - u.astype(lo)), off))
         return off, band
 
     return jax.lax.fori_loop(0, p, step, (off, band))
@@ -313,12 +326,18 @@ def loglik_distributed(off, band, z, t: int):
 
 def geostat_loglik_distributed(locs, z, theta, *, nb: int,
                                policy: PrecisionPolicy, nu_static=0.5,
+                               jitter: float = 1e-6,
                                version: str = "masked_full"):
-    """One full MLE likelihood evaluation, SPMD-shardable end to end."""
-    off, band = build_covariance_distributed(locs, theta, nb=nb,
-                                             policy=policy,
-                                             nu_static=nu_static)
-    t = min(policy.diag_thick, band.shape[0])
-    off, band = panel_cholesky_distributed(off, band, policy,
-                                           version=version)
-    return loglik_distributed(off, band, z, t)
+    """One full MLE likelihood evaluation, SPMD-shardable end to end;
+    `jitter` is added to the covariance's diagonal."""
+    with jax.named_scope("geostat_loglik_distributed"):
+        with jax.named_scope("cov_build"):
+            off, band = build_covariance_distributed(
+                locs, theta, nb=nb, policy=policy, nu_static=nu_static,
+                jitter=jitter)
+        t = min(policy.diag_thick, band.shape[0])
+        with jax.named_scope("factor"):
+            off, band = panel_cholesky_distributed(off, band, policy,
+                                                   version=version)
+        with jax.named_scope("solve"):
+            return loglik_distributed(off, band, z, t)

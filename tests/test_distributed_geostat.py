@@ -68,6 +68,19 @@ def test_distributed_full_policy_matches_dense(ds):
     assert ll == pytest.approx(ll_dense, abs=0.5)
 
 
+def test_distributed_takes_the_jitter(ds):
+    """`jitter` reaches the diagonal; its default is the build's 1e-6."""
+    pol = PrecisionPolicy.tpu(diag_thick=T)
+    f = jax.jit(lambda th, jitter: geostat_loglik_distributed(
+        ds.locs, ds.z, th, nb=NB, policy=pol, nu_static=0.5, jitter=jitter,
+        version="fori"))
+    default = float(geostat_loglik_distributed(
+        ds.locs, ds.z, ds.theta0, nb=NB, policy=pol, nu_static=0.5,
+        version="fori"))
+    assert float(f(ds.theta0, 1e-6)) == pytest.approx(default, rel=1e-6)
+    assert float(f(ds.theta0, 1e-2)) != pytest.approx(default, rel=1e-4)
+
+
 def test_distributed_jits(ds):
     pol = PrecisionPolicy.tpu(diag_thick=T)
     f = jax.jit(lambda th: geostat_loglik_distributed(

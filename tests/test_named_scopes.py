@@ -1,4 +1,5 @@
-"""Named scopes of the compiled likelihood step and kriging (DESIGN.md §13).
+"""Named scopes of the compiled likelihood step, the sharded likelihood
+and kriging (DESIGN.md §13).
 
 The scopes are HLO `op_name` metadata and nothing else: with `jax.named_scope`
 replaced by a null context, the lowered and the compiled programs are the
@@ -16,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.distributed import geostat_loglik_distributed
 from repro.core.kriging import krige
 from repro.core.panel_cholesky import geostat_loglik_step
 from repro.core.precision import PrecisionPolicy
@@ -35,7 +37,13 @@ SCOPES = {
     + [f"geostat_loglik_step/factor/{p}" for p in PANEL_PHASES],
     "krige": ["krige/cov_build", "krige/factor", "krige/solve"]
     + [f"krige/factor/{p}" for p in TILE_PHASES],
+    "dist": ["geostat_loglik_distributed/cov_build",
+             "geostat_loglik_distributed/factor",
+             "geostat_loglik_distributed/solve"],
 }
+# inside the sharded `fori` sweep the phases sit in the loop's body, under
+# `factor/while/body/...`
+DIST_PHASES = PANEL_PHASES
 
 
 def _inputs():
@@ -55,6 +63,12 @@ def _program(which, off_update="square"):
                                        nu_static=0.5, jitter=1e-6,
                                        off_update=off_update)
         return fn, (locs, z, theta)
+    if which == "dist":
+        def fn(locs, z, theta):
+            return geostat_loglik_distributed(locs, z, theta, nb=NB,
+                                              policy=POLICY, nu_static=0.5,
+                                              jitter=1e-6, version="fori")
+        return fn, (locs, z, theta)
 
     def fn(locs, z, obs, new, theta):
         return krige(locs[obs], z[obs], locs[new], theta, POLICY, nb=NB,
@@ -72,7 +86,8 @@ def _no_metadata(hlo: str) -> str:
     return re.sub(r", metadata=\{[^}]*\}", "", "\n".join(lines))
 
 
-CASES = [("eval", "square"), ("eval", "chunked"), ("krige", None)]
+CASES = [("eval", "square"), ("eval", "chunked"), ("krige", None),
+         ("dist", None)]
 
 
 @pytest.mark.parametrize("which,off_update", CASES)
@@ -91,6 +106,7 @@ def test_scopes_change_no_operation(monkeypatch, which, off_update):
     assert scoped.as_text() == plain.as_text()
     plain_names = " ".join(re.findall(r'op_name="([^"]*)"', plain_hlo))
     assert "geostat_loglik_step/" not in plain_names
+    assert "geostat_loglik_distributed/" not in plain_names
     assert "krige/" not in plain_names
     assert _no_metadata(scoped_hlo) == _no_metadata(plain_hlo)
 
@@ -102,6 +118,17 @@ def test_every_scope_is_in_the_compiled_metadata(which, off_update):
     op_names = set(re.findall(r'op_name="([^"]*)"', hlo))
     for scope in SCOPES[which]:
         assert any(f"/{scope}/" in name for name in op_names), scope
+
+
+def test_every_phase_of_the_sharded_sweep_is_in_the_compiled_metadata():
+    fn, args = _program("dist")
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    factor = "/geostat_loglik_distributed/factor/"
+    under = [name.split(factor)[1].split("/") for name in op_names
+             if factor in name]
+    for phase in DIST_PHASES:
+        assert any(phase in parts for parts in under), phase
 
 
 def test_scope_names_are_fixed_not_per_step():
