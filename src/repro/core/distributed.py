@@ -51,10 +51,60 @@ def _c_mat(x):
     return constrain(x, "geo_rows geo_cols")
 
 
+def _c_r2(x):
+    # fori-path sharding: rows 2-D (data x model), cols unsharded --
+    # traced-offset column slices cannot cross a sharded dim
+    return constrain(x, "geo_rows2d .")
+
+
+def _c_c2(x):
+    # the transpose of `_c_r2`: cols over both mesh axes, rows whole
+    return constrain(x, ". geo_rows2d")
+
+
+def _c_tiles(x):
+    # stacks of (nb, nb) tiles: each tile's rows over both mesh axes, the
+    # stack dims whole on every chip, so a tile read at a traced index
+    # is a local slice
+    return constrain(x, ". " * (x.ndim - 2) + "geo_rows2d .")
+
+
+def _update_band(band, c_lo, n_diag: int):
+    """band[i, d] -= C_i C_{i-d}^T for d < n_diag, C_i the (nb, nb) tiles
+    of the (n, nb) lo panel column C: the hi sub-diagonal updates
+    (dsyrk/dgemm band) of the `fori` sweep.  The panel's rows <= k are
+    zero, so sub-k products vanish on their own.
+
+    The panel becomes whole on every chip in the lo dtype and is cast to
+    the band's dtype there.  The band's tile index is whole on every chip
+    (`_c_tiles`), so the shifted operand is a local copy of the chip's own
+    rows: each chip updates the band rows it holds, from its rows of
+    C_{j+d} against the whole C_j.
+    """
+    p, _, nb, _ = band.shape
+    whole = constrain(c_lo.reshape(p, nb, nb), ". . .").astype(band.dtype)
+    rows = _c_tiles(whole)
+    for d in range(n_diag):
+        left = jnp.roll(rows, -d, axis=0) if d else rows
+        upd = jnp.einsum("iab,icb->iac", left, whole,
+                         preferred_element_type=band.dtype)
+        band = band.at[d:, d].add(-upd[:p - d])
+    return band
+
+
 def build_covariance_distributed(locs, theta, *, nb: int,
                                  policy: PrecisionPolicy, nu_static=0.5,
-                                 jitter: float = 1e-6):
-    """(off (n,n) lo sharded, band (p,t,nb,nb) hi) from the Matern kernel.
+                                 jitter: float = 1e-6,
+                                 version: str = "masked_full"):
+    """(off (n,n) lo sharded, band (p,t,nb,nb) hi) from the Matern kernel,
+    laid out as `panel_cholesky_distributed`'s `version` takes them.
+
+    The unrolled sweeps take off as the lower off-band storage, split over
+    both mesh axes, and the band split by tile index over "data".  The
+    `fori` sweep reads tiles at a traced step index, so it takes off
+    transposed (the upper storage; Sigma is symmetric), its columns split
+    over the whole mesh (`_c_c2`), and the band with each tile's rows
+    split over the whole mesh (`_c_tiles`): a tile at any step is local.
 
     Distances are coordinate differences (`pairwise_distance`, as on the
     one-chip path), fused elementwise into the sharded (n, n) build.  The
@@ -83,13 +133,16 @@ def build_covariance_distributed(locs, theta, *, nb: int,
             raise ValueError("distributed cov-gen uses half-integer nu")
         return theta1 * jnp.where(r == 0.0, 1.0, c)
 
-    cov = _corr(_c_mat(pairwise_distance(locs_hi, locs_hi)))
+    fori = version == "fori"
+    c_off = _c_c2 if fori else _c_mat
+    cov = _corr(c_off(pairwise_distance(locs_hi, locs_hi)))
 
     # off-band lower storage: band region + upper triangle zeroed so the
     # solve can use unmasked column matvecs
     ii = jnp.repeat(jnp.arange(p), nb)
     off_mask = (ii[:, None] - ii[None, :]) >= t
-    off = _c_mat(jnp.where(off_mask, cov, 0.0).astype(lo))
+    off = c_off(jnp.where(off_mask.T if fori else off_mask, cov,
+                          0.0).astype(lo))
 
     # hi band tiles built DIRECTLY from locations (slicing the sharded
     # (n, n) cov into 512 tiles gathered ~137 GB replicated stacks --
@@ -108,15 +161,18 @@ def build_covariance_distributed(locs, theta, *, nb: int,
         band_cols.append(blk)
     band = jnp.stack(band_cols, axis=1)
     band = band.at[:, 0].add(jitter * jnp.eye(nb, dtype=hi)[None])
+    if fori:
+        return off, _c_tiles(band)
     # shard the band storage: rows over data, tile rows over model
-    band = constrain(band, "geo_rows . geo_cols .")
-    return off, band
+    return off, constrain(band, "geo_rows . geo_cols .")
 
 
 def panel_cholesky_distributed(off, band, policy: PrecisionPolicy, *,
                                version: str = "masked_full",
                                align: int = 16):
-    """Factor in place; returns (off, band) with L in the same layout.
+    """Factor in place; returns (off, band) with L in the layout
+    `build_covariance_distributed` gives for the same `version`: off
+    holds L's lower storage, or L^T for `fori`.
 
     version:
       masked_full : p unrolled full-width masked steps (v1; ~3x FLOP waste)
@@ -210,96 +266,94 @@ def panel_cholesky_distributed(off, band, policy: PrecisionPolicy, *,
     return off, band
 
 
-def _c_r2(x):
-    # fori-path sharding: rows 2-D (data x model), cols unsharded --
-    # traced-offset column slices cannot cross a sharded dim
-    return constrain(x, "geo_rows2d .")
-
-
-def _panel_cholesky_fori(off, band, policy: PrecisionPolicy):
+def _panel_cholesky_fori(off_t, band, policy: PrecisionPolicy):
     """masked_full sweep as a single fori_loop body (all shapes static in
-    k; masks/slices use the traced k).  See panel_cholesky_distributed."""
+    k; masks/slices use the traced k).  See panel_cholesky_distributed.
+
+    Takes and returns off transposed, L^T split by columns: step k's
+    panel column of L is a row block of L^T, read, solved and written
+    back in the layout the triangular solve takes (its right-hand sides
+    along the minor dimension).  Held as L, the TPU compiler copied each
+    chip's whole share of the storage into that layout every step."""
     p, t, nb, _ = band.shape
     n = p * nb
-    hi = policy.hi
-    lo = off.dtype
-    row_tile = jnp.arange(p)
-    ii = jnp.repeat(row_tile, nb)
-    off = _c_r2(off)
+    lo = off_t.dtype
+    ii = jnp.repeat(jnp.arange(p), nb)
 
     def step(k, carry):
-        off, band = carry
+        off_t, band = carry
+        # band tiles at the traced k are local slices (`_c_tiles`); the
+        # diagonal tile alone is gathered, and factored on every chip
         with jax.named_scope("potrf"):
-            lkk = jnp.linalg.cholesky(band[k, 0])
+            lkk = constrain(jnp.linalg.cholesky(constrain(band[k, 0], ". .")),
+                            ". .")
             band = band.at[k, 0].set(lkk)
             lkk_lo = lkk.astype(lo)
 
-        # hi band-panel TRSMs (traced index, clamped + validity-masked)
+        # hi band-panel TRSMs (traced index, clamped + validity-masked),
+        # on the chip that holds each row of the tile
         with jax.named_scope("trsm_hi"):
+            panel = []
             for d in range(1, t):
                 idx = jnp.minimum(k + d, p - 1)
                 tile = band[idx, d]
-                upd = solve_triangular(lkk, tile.T, lower=True).T
+                upd_t = solve_triangular(lkk, tile.T, lower=True)
                 valid = (k + d) < p
-                band = band.at[idx, d].set(jnp.where(valid, upd, tile))
+                band = band.at[idx, d].set(jnp.where(valid, upd_t.T, tile))
+                panel.append(upd_t)
 
-        # lo panel TRSM over the full masked column
+        # lo panel TRSM over the full masked column (a row block of L^T)
         with jax.named_scope("trsm_lo"):
-            col = jax.lax.dynamic_slice(off, (0, k * nb), (n, nb))
-            col = _c_r2(col.astype(policy.solve_dtype))
-            sol = solve_triangular(lkk_lo.astype(policy.solve_dtype), col.T,
-                                   lower=True).T
-            row_mask = (ii >= k + t)[:, None]
-            col_new = jnp.where(row_mask, sol, col).astype(lo)
-            off = jax.lax.dynamic_update_slice(off, _c_r2(col_new),
-                                               (0, k * nb))
+            row = jax.lax.dynamic_slice(off_t, (k * nb, 0), (nb, n))
+            row = _c_c2(row.astype(policy.solve_dtype))
+            sol = solve_triangular(lkk_lo.astype(policy.solve_dtype), row,
+                                   lower=True)
+            col_mask = (ii >= k + t)[None, :]
+            row_new = jnp.where(col_mask, sol, row).astype(lo)
+            off_t = jax.lax.dynamic_update_slice(off_t, _c_c2(row_new),
+                                                 (k * nb, 0))
 
-        # assemble panel column: off rows (>= k+t) + hi band rows
+        # assemble the panel C^T, split by columns as L^T is: off columns
+        # (>= k+t) + the band tiles trsm_hi solved, each put in by a mask
+        # (a write at a traced column made GSPMD gather the whole panel)
         with jax.named_scope("gather"):
-            c_lo = jnp.where(row_mask, col_new, 0.0)
-            for d in range(1, t):
-                idx = jnp.minimum(k + d, p - 1)
-                cur = jax.lax.dynamic_slice(c_lo, (idx * nb, 0), (nb, nb))
-                tile = jnp.where((k + d) < p, band[idx, d].astype(lo), cur)
-                c_lo = jax.lax.dynamic_update_slice(c_lo, tile, (idx * nb, 0))
-            c_lo = _c_r2(c_lo)                   # rows <= k are zero
+            c_t = jnp.where(col_mask, row_new, 0.0)
+            for d, upd_t in enumerate(panel, start=1):
+                tiles = jnp.tile(constrain(upd_t.astype(lo), ". ."), (1, p))
+                c_t = jnp.where((ii == k + d)[None, :], tiles, c_t)
+            c_t = _c_c2(c_t)                     # cols <= k are zero
 
-        # hi sub-diagonal updates, roll-aligned (see unrolled variant)
         with jax.named_scope("update_hi"):
-            c_t = constrain(c_lo.reshape(p, nb, nb).astype(hi),
-                            "geo_rows geo_cols .")
-            for d in range(t):
-                shifted = jnp.roll(c_t, d, axis=0) if d else c_t
-                upd = jnp.einsum("iab,icb->iac", c_t, shifted,
-                                 preferred_element_type=hi)
-                wrap_ok = (row_tile >= d)[:, None, None]
-                band = band.at[:, d].add(-jnp.where(wrap_ok, upd, 0.0))
+            c_t = constrain(c_t, ". .")          # both updates read all of it
+            band = _update_band(band, c_t.T, t)
 
-        # lo off-band trailing update, full-width masked
+        # lo off-band trailing update, full-width masked, transposed
         with jax.named_scope("update_lo"):
-            u = lo_matmul(c_lo, c_lo.T, policy)
-            u = _c_r2(u)
-            mask = ((ii[:, None] - ii[None, :] >= t)
-                    & (ii[None, :] > k) & (ii[:, None] > k))
-            off = _c_r2(jnp.where(mask, (off - u.astype(lo)), off))
-        return off, band
+            u_t = _c_c2(lo_matmul(c_t.T, c_t, policy))
+            mask_t = ((ii[None, :] - ii[:, None] >= t)
+                      & (ii[:, None] > k) & (ii[None, :] > k))
+            off_t = _c_c2(jnp.where(mask_t, (off_t - u_t.astype(lo)), off_t))
+        return off_t, band
 
-    return jax.lax.fori_loop(0, p, step, (off, band))
+    return jax.lax.fori_loop(0, p, step, (_c_c2(off_t), _c_tiles(band)))
 
 
-def loglik_distributed(off, band, z, t: int):
-    """Blocked forward solve + logdet on the distributed layout.
+def loglik_distributed(off_t, band, z, t: int):
+    """Blocked forward solve + logdet on the distributed layout, from L^T
+    (`off_t`, the off-band storage as the `fori` sweep holds it).
 
     COLUMN-wise substitution: after solving block j, its contribution is
     pushed into the running residual with one (n, nb) column matvec --
     column slices keep their row sharding, unlike the row-strip variant
     whose per-step (nb, j*nb) gathers summed to ~256 GB/chip at n=512k
-    (dry-run iteration 2).  fori_loop body: the unrolled variant kept
+    (dry-run iteration 2).  Column j of L is row block j of L^T, a local
+    slice of `. geo_rows2d`; the band's tiles at the traced j are local
+    slices of `_c_tiles`.  fori_loop body: the unrolled variant kept
     p live copies of the (n, nb) fp32 columns (§Perf G5)."""
     p, _, nb, _ = band.shape
     n = p * nb
     hi = band.dtype
-    off = _c_r2(off)   # traced col slices below: cols must stay unsharded
+    off_t, band = _c_c2(off_t), _c_tiles(band)
 
     def step(j, carry):
         acc, w, logdet = carry
@@ -309,12 +363,12 @@ def loglik_distributed(off, band, z, t: int):
             wd = jax.lax.dynamic_slice(w, (idx * nb,), (nb,))
             contrib = band[j, d] @ wd
             rhs = rhs - jnp.where((j - d) >= 0, contrib, 0.0)
-        ljj = band[j, 0]
-        w_j = solve_triangular(ljj, rhs, lower=True)
+        ljj = constrain(band[j, 0], ". .")       # one tile, whole
+        w_j = solve_triangular(ljj, constrain(rhs, "."), lower=True)
         w = jax.lax.dynamic_update_slice(w, w_j, (j * nb,))
         logdet = logdet + jnp.sum(jnp.log(jnp.diagonal(ljj)))
-        col = jax.lax.dynamic_slice(off, (0, j * nb), (n, nb)).astype(hi)
-        acc = _c_r2(acc - col @ w_j[:, None])     # band rows of col are 0
+        col_t = jax.lax.dynamic_slice(off_t, (j * nb, 0), (nb, n)).astype(hi)
+        acc = _c_r2(acc - (w_j @ col_t)[:, None])  # band rows of col are 0
         return acc, w, logdet
 
     acc0 = _c_r2(z.astype(hi)[:, None])
@@ -334,10 +388,12 @@ def geostat_loglik_distributed(locs, z, theta, *, nb: int,
         with jax.named_scope("cov_build"):
             off, band = build_covariance_distributed(
                 locs, theta, nb=nb, policy=policy, nu_static=nu_static,
-                jitter=jitter)
+                jitter=jitter, version=version)
         t = min(policy.diag_thick, band.shape[0])
         with jax.named_scope("factor"):
             off, band = panel_cholesky_distributed(off, band, policy,
                                                    version=version)
         with jax.named_scope("solve"):
-            return loglik_distributed(off, band, z, t)
+            # the unrolled sweeps hold L, the solve reads L^T
+            off_t = off if version == "fori" else off.T
+            return loglik_distributed(off_t, band, z, t)

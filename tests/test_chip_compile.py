@@ -8,6 +8,7 @@ import, because only one process at a time may load the TPU library.
 
 import base64
 import contextlib
+import math
 import os
 import re
 from functools import partial
@@ -68,28 +69,91 @@ def test_loglik_step_compiles_for_one_v5e(topo, policy):
     assert 0 < _bytes(compiled) < V5E_HBM_BYTES
 
 
-@pytest.mark.parametrize("version", ["fori", "masked_full"])
-def test_distributed_loglik_is_sharded_over_v5e_2x2(topo, version):
+def _sharded(topo, fn, n, *, args=("locs", "z", "theta")):
+    """`fn` compiled on the described 2x2 mesh with the benchmark's input
+    shardings: `locs` and `z` rows over "data", theta on every chip."""
     mesh = make_geostat_mesh(topo.devices)
     spec = lambda shape, *axes: jax.ShapeDtypeStruct(
         shape, jnp.float32, sharding=NamedSharding(mesh, P(*axes)))
-    fn = jax.jit(partial(geostat_loglik_distributed, nb=NB,
-                         policy=PrecisionPolicy.tpu(2), nu_static=0.5,
-                         version=version))
-    build = jax.jit(partial(build_covariance_distributed, nb=NB,
-                            policy=PrecisionPolicy.tpu(2), nu_static=0.5))
-    locs, z, theta = (spec((N, 2), "data", None), spec((N,), "data"),
-                      spec((2,)))
+    specs = {"locs": spec((n, 2), "data", None), "z": spec((n,), "data"),
+             "theta": spec((2,))}
     set_activation_mesh(mesh)
     try:
-        compiled = fn.lower(locs, z, theta).compile()
-        off_sharding = build.lower(locs, theta).compile().output_shardings[0]
+        return jax.jit(fn).lower(*(specs[a] for a in args)).compile()
     finally:
         set_activation_mesh(None)
+
+
+@pytest.mark.parametrize("version", ["fori", "masked_full"])
+def test_distributed_loglik_is_sharded_over_v5e_2x2(topo, version):
+    policy = PrecisionPolicy.tpu(2)
+    compiled = _sharded(topo, partial(geostat_loglik_distributed, nb=NB,
+                                      policy=policy, nu_static=0.5,
+                                      version=version), N)
+    build = _sharded(topo, partial(build_covariance_distributed, nb=NB,
+                                   policy=policy, nu_static=0.5), N,
+                     args=("locs", "theta"))
     # constrain() split the (n, n) off-band storage over all 4 devices;
     # the row-sharded inputs alone would leave each device n/2 full rows
-    assert off_sharding.shard_shape((N, N)) == (N // 2, N // 2)
+    assert build.output_shardings[0].shard_shape((N, N)) == (N // 2, N // 2)
     assert 0 < _bytes(compiled) < V5E_HBM_BYTES
+
+
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+                "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8}
+_INSTRUCTION = re.compile(r"\s*(?:ROOT )?%\S+ = (.+?) ([a-z-]+)\(")
+_COLLECTIVES = ("all-gather", "all-reduce", "collective-permute",
+                "all-to-all", "reduce-scatter")
+
+
+def _largest_array(result_type: str) -> tuple:
+    """(bytes, elements) of the largest array in an HLO result type; a
+    `-start` op's tuple holds its operand and its result."""
+    arrays = [(_DTYPE_BYTES[dt], math.prod(int(d) for d in dims.split(",")
+                                           if d))
+              for dt, dims in re.findall(r"\b([a-z0-9]+)\[([0-9,]*)\]",
+                                         result_type) if dt in _DTYPE_BYTES]
+    return max(((b * e, e) for b, e in arrays), default=(0, 0))
+
+
+def test_sharded_sweep_moves_tiles_not_the_band_on_v5e_2x2(topo):
+    """The `fori` sweep reads and writes each band tile at the traced step
+    on the chips that hold it.  At p = 8 a tile is a sixteenth of the f32
+    band: no collective under the loop bodies' `potrf`, `trsm_hi` and
+    `gather`, or in the solve's loop, returns more than a step's t tiles
+    per chip; none under `update_hi`, where the panel becomes whole on
+    every chip, returns more than the (n, nb) panel in bf16; and no copy
+    under `potrf` or `trsm_hi` is as large as a chip's share of the
+    band."""
+    n, nb, policy = 4096, 512, PrecisionPolicy.tpu(2)
+    p, t = n // nb, policy.diag_thick
+    compiled = _sharded(topo, partial(geostat_loglik_distributed, nb=nb,
+                                      policy=policy, nu_static=0.5,
+                                      version="fori"), n)
+    body = "/factor/while/body/closed_call/"
+    moving = tuple(body + phase + "/" for phase in
+                   ("potrf", "trsm_hi", "gather")) + ("/solve/while/body/",)
+    copying = (body + "potrf/", body + "trsm_hi/")
+    moved, panel, copied = [], [], []
+    for line in compiled.as_text().splitlines():
+        op = _INSTRUCTION.match(line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if not (op and name):
+            continue
+        nbytes, elems = _largest_array(op.group(1))
+        if (op.group(2).startswith(_COLLECTIVES)
+                and any(s in name.group(1) for s in moving)):
+            moved.append((nbytes, name.group(1)))
+        if (op.group(2).startswith(_COLLECTIVES)
+                and body + "update_hi/" in name.group(1)):
+            panel.append((nbytes, name.group(1)))
+        if (op.group(2) in ("copy", "copy-start")
+                and any(s in name.group(1) for s in copying)):
+            copied.append((elems, name.group(1)))
+    assert moved                      # the phases are found in the program
+    assert max(moved)[0] <= t * nb * nb * 4, max(moved)
+    assert max(panel, default=(0, ""))[0] <= n * nb * 2, max(panel)
+    assert max(copied, default=(0, ""))[0] < p * t * nb * nb // 4, max(copied)
 
 
 def _step(topo, policy, n=N, nb=NB, off_update="chunked"):

@@ -1,5 +1,6 @@
 """The sharded `fori` likelihood on a 2x2 mesh of four host devices against
-the fp64 reference and the one-chip step.
+the fp64 reference and the one-chip step, and its factor against the
+unrolled `masked_full` sweep's.
 
 XLA's CPU backend gives four devices only when told so before JAX starts,
 so the programs run in a child process
@@ -33,13 +34,21 @@ TOL_REF = 2e-3
 # sits 5e-3 or more away.  5e-4 is 4x the largest.
 TOL_STEP = 5e-4
 
+# max |x_fori - x_unrolled| / max |x_unrolled|, for the factored band and
+# off: both sweeps read 0 on the three fields (the same operations on the
+# same tiles); one rounding of the bf16 off tiles moved the wrong way
+# would read about 4e-3.
+TOL_FACTOR = 1e-6
+
 CHILD = r"""
 import json, sys
 from functools import partial
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import PrecisionPolicy
-from repro.core.distributed import geostat_loglik_distributed
+from repro.core.distributed import (build_covariance_distributed,
+                                    geostat_loglik_distributed,
+                                    panel_cholesky_distributed)
 from repro.core.panel_cholesky import geostat_loglik_step
 from repro.covariance import make_dataset
 from repro.launch.mesh import make_geostat_mesh
@@ -74,7 +83,29 @@ def sharded(lo):
         set_activation_mesh(None)
 
 
+def factored(version):
+    def fn(locs, theta):
+        off, band = build_covariance_distributed(
+            locs, theta, nb=NB, policy=policy("bfloat16"), nu_static=0.5,
+            jitter=JITTER, version=version)
+        return panel_cholesky_distributed(off, band, policy("bfloat16"),
+                                          version=version)
+    set_activation_mesh(mesh)
+    try:
+        return jax.jit(fn).lower(
+            jax.ShapeDtypeStruct((N, 2), jnp.float32, sharding=rows),
+            jax.ShapeDtypeStruct((2,), jnp.float32, sharding=rep)).compile()
+    finally:
+        set_activation_mesh(None)
+
+
+def gap(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.abs(a - b).max() / np.abs(b).max())
+
+
 programs = {lo: sharded(lo) for lo in ("bfloat16", "float8_e4m3fn")}
+factors = {v: factored(v) for v in ("fori", "masked_full")}
 step = jax.jit(partial(geostat_loglik_step, nb=NB, policy=policy("bfloat16"),
                        nu_static=0.5, jitter=JITTER))
 out = {"devices": len(devs),
@@ -90,9 +121,13 @@ for seed in json.loads(sys.argv[1]):
     cov = THETA[0] * np.exp(-r / float(theta[1])) + JITTER * np.eye(N)
     args = (jax.device_put(ds.locs, rows), jax.device_put(ds.z, vec),
             jax.device_put(theta, rep))
+    (off, band), (off_u, band_u) = (factors[v](args[0], args[2])
+                                    for v in ("fori", "masked_full"))
     out["fields"].append({
         "seed": seed, "ref": exact_loglik(cov, np.asarray(ds.z)),
         "step": float(step(ds.locs, ds.z, theta)),
+        "factor_gap": {"off": gap(off, np.asarray(off_u).T),  # fori: L^T
+                       "band": gap(band, band_u)},
         **{lo: float(exe(*args)) for lo, exe in programs.items()}})
 print(json.dumps(out))
 """
@@ -132,6 +167,18 @@ def test_sharded_fori_matches_the_fp64_reference(readings, seed):
 def test_sharded_fori_matches_the_one_chip_step(readings, seed):
     f = readings["fields"][seed]
     assert _rel(f["bfloat16"], f["step"], f["ref"]) <= TOL_STEP
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_sharded_fori_factors_as_the_unrolled_sweep(readings, seed):
+    """The `fori` body reads and writes the band's tiles at the traced
+    step, in its own layout (L^T, each tile's rows split over the mesh);
+    the unrolled `masked_full` sweep, which shares no code with that
+    body, slices them statically from L's storage.  Both factor the same
+    tiles in the same precisions, so the factored band and off-band
+    storage agree to within f32 rounding."""
+    gaps = readings["fields"][seed]["factor_gap"]
+    assert gaps["band"] <= TOL_FACTOR and gaps["off"] <= TOL_FACTOR
 
 
 @pytest.mark.parametrize("seed", SEEDS)
